@@ -66,6 +66,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 import jax
 import numpy as np
 
+from repro.launch.runtime import use_compile_cache
 from repro.serving import EngineConfig, EngineMetrics, Request, ServeEngine
 
 MIXES = {
@@ -789,6 +790,7 @@ def compare_spec(sc, args) -> dict:
 
 
 def main() -> None:
+    use_compile_cache(str(Path(__file__).resolve().parent.parent))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="registry arch (default: inline tiny model)")

@@ -1,6 +1,7 @@
 """Public kernel API: jit-friendly wrappers that dispatch between the pure
 jnp reference paths, the scan-based blockwise implementations, and the
-Pallas TPU kernels (validated in interpret mode on CPU).
+Pallas TPU kernels (compiled on TPU; ``interpret=True`` runs them on the
+CPU, where the tests check them against the oracles).
 
   multi_head_attention : direct softmax / blockwise flash / Pallas flash
   expert_gemm          : batched per-expert GEMM (MoE)

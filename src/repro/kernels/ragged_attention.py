@@ -7,7 +7,7 @@ in-flight prompt into one ragged ``(T, Hq, D)`` query batch.  Each
 *segment* of that batch (one decode slot or one prefill chunk) attends
 against exactly the KV pages its request owns:
 
-  grid = (S * Hkv, max_pages) — S segments x kv heads outer, the segment's
+  grid = (Hkv * S, max_pages) — kv heads x S segments outer, the segment's
   page walk inner.  The segment table (``q_start``/``q_len``/``kv_len``)
   and the per-segment page table ride in as scalar-prefetch operands, so
   the K/V BlockSpec index maps steer each grid step's DMA to the page the
@@ -31,10 +31,16 @@ against exactly the KV pages its request owns:
 
 K/V pools use the resident ``(P, Hkv, page_size, D)`` layout (head axis
 ahead of the page-token axis), so one (page, head) tile is a contiguous
-block and no transpose happens per call.
+block and no transpose happens per call.  Queries go to the kernel
+head-major, ``(Hkv, T, G, D)``: the q index map picks the kv head's query
+group, and the body's per-segment row slice lands on an untiled leading
+axis.  (Slicing query heads inside the kernel put a dynamic offset on the
+sublane axis, which Mosaic refuses.)
 
-Validated against :func:`repro.kernels.ref.ragged_paged_reference` in
-interpret mode (tests + property tests over random packings).
+Runs compiled on TPU; validated against
+:func:`repro.kernels.ref.ragged_paged_reference` in interpret mode (tests
++ property tests over random packings) and compiled for a described v5e
+chip in ``tests/test_tpu_compile.py``.
 """
 
 from __future__ import annotations
@@ -51,18 +57,22 @@ from .ref import ragged_pack_indices
 
 def _ragged_kernel(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref, v_ref,
                    o_ref, acc_ref, m_ref, l_ref, *, sm_scale: float,
-                   page_size: int, n_pages: int, hkv: int, g: int,
+                   page_size: int, n_pages: int, n_segs: int, g: int,
                    max_q: int):
-    """Grid (S * Hkv, max_pages).  ``pt_ref`` (S, max_pages) and the
+    """Grid (Hkv * S, max_pages).  ``pt_ref`` (S, max_pages) and the
     (S,) segment table ``qs/ql/kl`` are scalar-prefetch operands; the K/V
-    index maps already walked them, so the body only masks and combines."""
-    sh, j = pl.program_id(0), pl.program_id(1)
-    s = sh // hkv
-    h = sh % hkv
+    index maps already walked them, so the body only masks and combines.
+    ``q_ref`` is the (1, T + max_q, G, D) head-major block of this step's
+    kv head, picked by its index map."""
+    hs, j = pl.program_id(0), pl.program_id(1)
+    s = hs % n_segs
     qs = qs_ref[s]
     ql = ql_ref[s]
     kl = kl_ref[s]
     q2 = max_q * g
+    # float32 operands contract at float32 (what a float32 reference
+    # compares against); bf16 ones take the MXU's native pass
+    prec = jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32 else None
 
     @pl.when(j == 0)
     def _init():
@@ -74,10 +84,11 @@ def _ragged_kernel(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref, v_ref,
         d = q_ref.shape[-1]
         # the segment's fixed-width query tile: (max_q, G, D) rows past
         # q_len are masked below
-        qt = q_ref[pl.ds(qs, max_q), pl.ds(h * g, g), :]
+        qt = q_ref[0, pl.ds(qs, max_q), :, :]
         qf = qt.reshape(q2, d).astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)  # (page_size, D)
         sc = jax.lax.dot_general(qf, k, (((1,), (1,)), ((), ())),
+                                 precision=prec,
                                  preferred_element_type=jnp.float32)
         sc = sc * sm_scale  # (q2, page_size)
         # row r of the flattened tile is query i = r // g of the segment,
@@ -96,7 +107,7 @@ def _ragged_kernel(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref, v_ref,
         v = v_ref[0, 0].astype(jnp.float32)
         acc_ref[...] = (acc_ref[...] * alpha[:, None]
                         + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
+                            p, v, (((1,), (0,)), ((), ())), precision=prec,
                             preferred_element_type=jnp.float32))
         m_ref[...] = m_new
 
@@ -140,29 +151,38 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, seg_page_table, q_start,
     g = hq // hkv
     scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
 
-    # pad the token axis so a fixed-width tile starting at any q_start
-    # stays in bounds (padding rows are masked by q_len)
-    qp = jnp.pad(q, ((0, max_q), (0, 0), (0, 0)))
+    # head-major (Hkv, T + max_q, G, D): the index map picks the kv head's
+    # query group, so the segment's dynamic row offset falls on an untiled
+    # leading axis and never on the tiled (G, D) plane.  The token axis is
+    # padded so a fixed-width tile starting at any q_start stays in bounds
+    # (padding rows are masked by q_len).
+    qh = jnp.pad(q, ((0, max_q), (0, 0), (0, 0))).reshape(t + max_q, hkv, g, d)
+    qh = jnp.moveaxis(qh, 1, 0)
 
     kernel = functools.partial(_ragged_kernel, sm_scale=scale, page_size=ps,
-                               n_pages=max_pages, hkv=hkv, g=g, max_q=max_q)
+                               n_pages=max_pages, n_segs=s_count, g=g,
+                               max_q=max_q)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # seg_page_table, q_start, q_len, kv_len
-        grid=(s_count * hkv, max_pages),
+        # kv head outer, segment inner: one head's q block stays resident
+        # across every segment
+        grid=(hkv * s_count, max_pages),
         in_specs=[
-            # the whole packed q rides in VMEM (T is one step's tokens —
-            # max_slots + prefill_rows * chunk — not a context length)
-            pl.BlockSpec((t + max_q, hq, d),
-                         lambda sh, j, pt, qs, ql, kl: (0, 0, 0)),
+            # one kv head's whole packed q group rides in VMEM (T is one
+            # step's tokens — max_slots + prefill_rows * chunk — not a
+            # context length)
+            pl.BlockSpec((1, t + max_q, g, d),
+                         lambda hs, j, pt, qs, ql, kl: (hs // s_count,
+                                                        0, 0, 0)),
             pl.BlockSpec((1, 1, ps, d),
-                         lambda sh, j, pt, qs, ql, kl: (pt[sh // hkv, j],
-                                                        sh % hkv, 0, 0)),
+                         lambda hs, j, pt, qs, ql, kl: (pt[hs % s_count, j],
+                                                        hs // s_count, 0, 0)),
             pl.BlockSpec((1, 1, ps, d),
-                         lambda sh, j, pt, qs, ql, kl: (pt[sh // hkv, j],
-                                                        sh % hkv, 0, 0)),
+                         lambda hs, j, pt, qs, ql, kl: (pt[hs % s_count, j],
+                                                        hs // s_count, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, max_q, g, d),
-                               lambda sh, j, pt, qs, ql, kl: (sh, 0, 0, 0)),
+                               lambda hs, j, pt, qs, ql, kl: (hs, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((max_q * g, d), jnp.float32),
             pltpu.VMEM((max_q * g,), jnp.float32),
@@ -172,13 +192,13 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, seg_page_table, q_start,
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_count * hkv, max_q, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((hkv * s_count, max_q, g, d), q.dtype),
         interpret=interpret,
     )(jnp.asarray(seg_page_table, jnp.int32),
       jnp.asarray(q_start, jnp.int32), jnp.asarray(q_len, jnp.int32),
-      jnp.asarray(kv_len, jnp.int32), qp, k_pool, v_pool)
-    # (S*Hkv, max_q, G, D) -> segment-major (S, max_q, Hq, D) -> re-pack
-    o = o.reshape(s_count, hkv, max_q, g, d)
-    o = jnp.moveaxis(o, 1, 2).reshape(s_count * max_q, hq, d)
+      jnp.asarray(kv_len, jnp.int32), qh, k_pool, v_pool)
+    # (Hkv*S, max_q, G, D) -> segment-major (S, max_q, Hq, D) -> re-pack
+    o = o.reshape(hkv, s_count, max_q, g, d)
+    o = jnp.moveaxis(o, 0, 2).reshape(s_count * max_q, hq, d)
     idx = ragged_pack_indices(q_start, q_len, t, max_q)
     return jnp.take(o, idx, axis=0)

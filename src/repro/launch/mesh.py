@@ -6,8 +6,8 @@ DP/FSDP across the other axis.  Multi-pod: 2 pods = 512 chips with a leading
 "pod" axis over the slower inter-pod DCN, used for data parallelism (or
 pipeline stages via ``repro.training.pipeline``).
 
-Mesh construction goes through ``repro.compat.make_mesh`` so it works on
-JAX 0.4.x (no ``AxisType``) and 0.5+ alike.
+Every axis is ``AxisType.Auto``: sharding is propagated by the compiler,
+never checked against explicit types.
 
 This module never touches jax device state at import time; meshes are built
 inside functions so the dry-run's ``xla_force_host_platform_device_count``
@@ -18,18 +18,20 @@ from __future__ import annotations
 
 import jax
 
-from ..compat import make_mesh as _compat_make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests / small runs)."""
-    return _compat_make_mesh(shape, axes)
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int | None = None):

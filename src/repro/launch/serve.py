@@ -3,7 +3,9 @@
     PYTHONPATH=src python -m repro.launch.serve --arch deepseek-moe-16b \
         --requests 8 --max-new 16 [--devices 4 --tp 2]
 
-Reduced configs on CPU (full configs are sized for real pods).  Prints
+Reduced configs (full configs are sized for real pods).  ``--devices N``
+gives the CPU N virtual devices under ``JAX_PLATFORMS=cpu``; on an
+accelerator the real devices are used.  Prints
 per-request outputs + engine throughput; ``--n-spec K`` serves through
 the unified engine with batched speculative decoding (self-draft: the
 target verifies its own proposals, so greedy outputs are unchanged and
@@ -14,16 +16,9 @@ import argparse
 import os
 import sys
 
+from .runtime import force_host_devices
 
-def _early_devices() -> None:
-    if "--devices" in sys.argv:
-        n = sys.argv[sys.argv.index("--devices") + 1]
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={n} "
-            + os.environ.get("XLA_FLAGS", ""))
-
-
-_early_devices()
+force_host_devices(sys.argv)
 
 import time  # noqa: E402
 
@@ -36,6 +31,7 @@ from ..models import build_model  # noqa: E402
 from ..serving import EngineConfig, Request, ServeEngine  # noqa: E402
 from ..serving.sampling import SamplingConfig  # noqa: E402
 from .mesh import make_mesh  # noqa: E402
+from .runtime import use_compile_cache  # noqa: E402
 
 
 def main() -> None:
@@ -54,6 +50,8 @@ def main() -> None:
                     help="draft window K for batched speculative decoding "
                          "(self-draft; implies the unified paged engine)")
     args = ap.parse_args()
+    use_compile_cache(os.path.join(os.path.dirname(__file__), "..", "..",
+                                   ".."))
 
     spec = registry.get_reduced(args.arch)
     if not spec.decoder:

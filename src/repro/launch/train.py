@@ -5,25 +5,18 @@
 
 Runs the fault-tolerant trainer on the chosen architecture (reduced config
 by default on CPU; the full config is for real fleets), with checkpointing,
-straggler monitoring and deterministic resume.  ``--devices N`` fakes an
-N-chip host for a sharded run (must be set before jax initializes, hence
-the env hop at the top).
+straggler monitoring and deterministic resume.  ``--devices N`` under
+``JAX_PLATFORMS=cpu`` fakes an N-chip host for a sharded run (set before
+jax initializes, hence the call at the top); on an accelerator the real
+devices are used.
 """
 
 import argparse
-import os
 import sys
 
+from .runtime import force_host_devices
 
-def _early_devices() -> None:
-    if "--devices" in sys.argv:
-        n = sys.argv[sys.argv.index("--devices") + 1]
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={n} "
-            + os.environ.get("XLA_FLAGS", ""))
-
-
-_early_devices()
+force_host_devices(sys.argv)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

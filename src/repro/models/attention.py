@@ -1,12 +1,17 @@
 """Multi-head / grouped-query attention layer with KV cache.
 
-Three execution paths, selected by ``ModelContext.attn_impl``:
+Three execution paths over dense K/V, selected by
+``ModelContext.attn_impl``:
 
   direct : plain einsum softmax (small sequences, and the decode step)
   flash  : scan-based blockwise attention (``repro.kernels.flash_jnp``) —
            memory-bounded, custom VJP; what the dry run lowers
-  pallas : the TPU Pallas kernel (``repro.kernels.flash_attention``),
-           validated in interpret mode on CPU
+  pallas : the TPU Pallas kernel (``repro.kernels.flash_attention``)
+
+The paged decode and token-packed paths run the Pallas paged/ragged
+kernels on TPU and their gather oracles elsewhere
+(:meth:`ModelContext.paged_kernel`); Pallas runs compiled on TPU and in
+interpret mode on CPU.
 
 KV cache layouts (``ModelContext.cache_layout``):
 
@@ -257,7 +262,7 @@ def _attend(spec: ModelSpec, ctx: ModelContext, q, k, v, *, causal,
     window = spec.attn.window if spec.attn.kind == "swa" else None
     sq, skv = q.shape[1], k.shape[1]
     impl = ctx.attn_impl
-    if impl == "auto":
+    if impl in ("auto", "gather"):  # "gather" names the paged oracle only
         # direct path materializes (B, H, Sq, Skv) scores: only for short
         # full passes and single-token decode steps.
         impl = "direct" if (sq * skv <= 1024 * 1024 and sq > 1) or sq <= 16 \
@@ -319,9 +324,10 @@ def _paged_attention(spec: ModelSpec, ctx: ModelContext, cache:
         k_scale=scat(cache.k_scale, k_sc) if quant else None,
         v_scale=scat(cache.v_scale, v_sc) if quant else None)
 
-    if ctx.attn_impl == "pallas" and not quant:
+    impl, interpret = ctx.paged_kernel()
+    if impl == "pallas" and not quant:
         o = kops.paged_decode_attention(q, kc, vc, page_table, lengths + 1,
-                                        impl="pallas")
+                                        impl="pallas", interpret=interpret)
     else:
         ka = paged_gather(kc, page_table)
         va = paged_gather(vc, page_table)
@@ -379,15 +385,15 @@ def _packed_paged_attention(spec: ModelSpec, ctx: ModelContext,
         k_scale=scat(cache.k_scale, k_sc) if quant else None,
         v_scale=scat(cache.v_scale, v_sc) if quant else None)
 
-    if ctx.attn_impl == "pallas" and not quant:
-        impl, ka, va = "pallas", kc, vc
-    else:
-        impl, ka, va = "gather", kc, vc
-        if quant:
-            ka = (kc.astype(jnp.float32)
-                  * new_cache.k_scale[..., None]).astype(k.dtype)
-            va = (vc.astype(jnp.float32)
-                  * new_cache.v_scale[..., None]).astype(v.dtype)
+    # the ragged kernel takes no scale operands: int8 KV keeps the oracle
+    impl, interpret = ctx.paged_kernel()
+    ka, va = kc, vc
+    if quant:
+        impl, interpret = "gather", False
+        ka = (kc.astype(jnp.float32)
+              * new_cache.k_scale[..., None]).astype(k.dtype)
+        va = (vc.astype(jnp.float32)
+              * new_cache.v_scale[..., None]).astype(v.dtype)
 
     nd = packed.n_decode
     dq = packed.decode_q
@@ -399,16 +405,18 @@ def _packed_paged_attention(spec: ModelSpec, ctx: ModelContext,
         o_dec = kops.ragged_paged_attention(
             q[0, :nd * dq], ka, va, packed.page_table[:nd],
             packed.q_start[:nd], packed.q_len[:nd], packed.kv_len[:nd],
-            max_q=dq, impl=impl)
+            max_q=dq, impl=impl, interpret=interpret)
         o_pre = kops.ragged_paged_attention(
             q[0, nd * dq:], ka, va, packed.page_table[nd:],
             packed.q_start[nd:] - nd * dq, packed.q_len[nd:],
-            packed.kv_len[nd:], max_q=packed.max_q, impl=impl)
+            packed.kv_len[nd:], max_q=packed.max_q, impl=impl,
+            interpret=interpret)
         o = jnp.concatenate([o_dec, o_pre], axis=0)
     else:
         o = kops.ragged_paged_attention(
             q[0], ka, va, packed.page_table, packed.q_start, packed.q_len,
-            packed.kv_len, max_q=packed.max_q, impl=impl)
+            packed.kv_len, max_q=packed.max_q, impl=impl,
+            interpret=interpret)
     return o[None], new_cache
 
 

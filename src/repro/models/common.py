@@ -24,7 +24,10 @@ class ModelContext:
     policy: ShardingPolicy = field(default_factory=lambda: get_policy("inference_tp"))
     param_dtype: Any = jnp.bfloat16
     compute_dtype: Any = jnp.bfloat16
-    #: attention implementation: auto | direct | flash | pallas
+    #: attention implementation: auto | direct | flash | pallas | gather.
+    #: "auto" picks direct/flash for dense caches and, for the paged and
+    #: token-packed paths, the Pallas kernels on TPU and the gather oracle
+    #: elsewhere (:meth:`paged_kernel`); an explicit value wins.
     attn_impl: str = "auto"
     flash_block_q: int = 512
     flash_block_kv: int = 1024
@@ -65,6 +68,23 @@ class ModelContext:
 
     def with_(self, **kw) -> "ModelContext":
         return replace(self, **kw)
+
+    def paged_kernel(self) -> tuple[str, bool]:
+        """``(impl, interpret)`` of the paged decode and ragged kernels.
+
+        ``attn_impl="auto"`` resolves, when the program is traced, from the
+        devices it runs on (the mesh's, else the default backend): the
+        Pallas kernels on TPU, the gather oracle anywhere else.  "pallas"
+        and "gather" are taken as given.  Pallas compiles for TPU and runs
+        in interpret mode only where there is none."""
+        platform = (self.mesh.devices.flat[0].platform if self.mesh is not None
+                    else jax.default_backend())
+        impl = self.attn_impl
+        if impl == "auto":
+            impl = "pallas" if platform == "tpu" else "gather"
+        if impl == "pallas":
+            return "pallas", platform != "tpu"
+        return "gather", False
 
 
 # ---------------------------------------------------------------------------

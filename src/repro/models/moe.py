@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..core.modelspec import ModelSpec
 from .common import KeyGen, ModelContext, activation, dense_init, rms_norm
 from .mlp import init_mlp, mlp_axes, mlp_block
@@ -244,8 +243,8 @@ def _moe_shardmap(spec: ModelSpec, ctx: ModelContext, params: dict,
 
     body = functools.partial(_moe_shardmap_body, spec, e_local, c_send,
                              c_cap, m_sz, partition, "model")
-    fn = shard_map(body, mesh=mesh, in_specs=(param_specs, x_spec),
-                   out_specs=x_spec, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(param_specs, x_spec),
+                       out_specs=x_spec, check_vma=False)
     return fn(body_params, h)
 
 

@@ -7,7 +7,8 @@
 
 The analytical backend is pure Python (no JAX), so sweeps fan out over a
 forked process pool — the paper's figures are thousands of independent
-cells and evaluate embarrassingly parallel.  Order is preserved:
+cells and evaluate embarrassingly parallel.  A process that already holds
+an accelerator never forks; it prices serially.  Order is preserved:
 ``reports[i]`` corresponds to ``scenarios[i]``.  The engine backend runs
 serially (one JAX device pool, one engine at a time).
 """
@@ -15,6 +16,7 @@ serially (one JAX device pool, one engine at a time).
 from __future__ import annotations
 
 import os
+import sys
 from typing import Iterable, Sequence
 
 from .report import Report
@@ -61,18 +63,34 @@ def run(scenarios: Scenario | Sweep | Iterable[Scenario], *,
     return _run_analytical(scs, max_workers)
 
 
+def _holds_accelerator() -> bool:
+    """True once this process has started a non-CPU JAX backend.  A
+    forked child would inherit its handle on the chip, which belongs to
+    one process at a time, so such a process prices serially."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized() \
+        and jax.default_backend() != "cpu"
+
+
 def _run_analytical(scs: Sequence[Scenario],
                     max_workers: int | None) -> list[Report]:
     from . import analytical
     workers = (os.cpu_count() or 1) if max_workers is None else max_workers
     workers = min(workers, len(scs))
-    if workers <= 1 or len(scs) < _PARALLEL_THRESHOLD:
+    if workers <= 1 or len(scs) < _PARALLEL_THRESHOLD \
+            or _holds_accelerator():
         return [analytical.evaluate(sc) for sc in scs]
     try:
         return _pool_map(scs, workers)
-    except Exception:  # noqa: BLE001 - no fork / broken pool / sandbox
+    except OSError:  # the host refused to fork: price serially
         _shutdown_pool()
         return [analytical.evaluate(sc) for sc in scs]
+    except BaseException:  # a broken pool is an error, not a fallback
+        _shutdown_pool()
+        raise
 
 
 # The worker pool is cached across run() calls: sweeps are often issued

@@ -59,7 +59,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import tree
 from ..models.model import Model, ModelCache
 from .engine import EngineConfig, Request, ServeEngine
 from .paging import PageAllocator
@@ -77,7 +76,7 @@ def _migrate_pages(dst_layers, src_layers, src_ids, dst_ids):
         pages = jnp.take(src, src_ids, axis=1)
         return dst.at[:, dst_ids].set(pages.astype(dst.dtype))
 
-    return tree.map(cp, dst_layers, src_layers)
+    return jax.tree.map(cp, dst_layers, src_layers)
 
 
 @dataclass(frozen=True)

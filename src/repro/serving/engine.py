@@ -90,7 +90,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import tree
 from ..models.attention import (PackedSegs, PagedAttnCache,
                                 paged_insert_rows)
 from ..models.model import Model, ModelCache
@@ -439,6 +438,12 @@ class ServeEngine:
         self.steps = 0
         self.metrics = EngineMetrics()
 
+        # (pp, tp) device mesh of the sharded unified step (None: one
+        # device); the paged pools are created already split over it
+        self.tp, self.pp = config.tp, config.pp
+        self.mesh = shard.make_engine_mesh(self.tp, self.pp) \
+            if self.tp * self.pp > 1 else None
+
         self.max_pages = config.max_seq // config.page_size
         self.pager: PageAllocator | None = None
         self._ptab = None  # host mirror of the device page table
@@ -451,8 +456,12 @@ class ServeEngine:
                                        page_size=config.page_size)
             self._ptab = np.zeros((config.max_slots, self.max_pages),
                                   np.int32)
-            self.cache = model.init_cache(config.max_slots, config.max_seq,
-                                          layout="paged", n_pages=n_pages)
+            init = functools.partial(model.init_cache, config.max_slots,
+                                     config.max_seq, layout="paged",
+                                     n_pages=n_pages)
+            self.cache = init() if self.mesh is None else shard.init_sharded(
+                init, shard.cache_pspecs(model, self.tp, self.pp),
+                self.mesh)
         else:
             self.cache = model.init_cache(config.max_slots, config.max_seq,
                                           layout="dense")
@@ -514,22 +523,17 @@ class ServeEngine:
         self._dev_ptab = None
 
         # -- mesh-sharded serving (tp/pp > 1) ---------------------------------
-        # place params and the paged pools ONCE with their (pp, tp)
-        # NamedShardings so steady-state dispatches reshard nothing; the
-        # per-profile collective counts are static functions of the packed
-        # geometry, accumulated into metrics after each dispatch
-        self.tp, self.pp = config.tp, config.pp
-        self.mesh = shard.make_engine_mesh(self.tp, self.pp) \
-            if self.tp * self.pp > 1 else None
+        # place params ONCE with their (pp, tp) NamedShardings (a no-op for
+        # params made by ``shard.init_sharded``) so steady-state dispatches
+        # reshard nothing; the per-profile collective counts are static
+        # functions of the packed geometry, accumulated into metrics after
+        # each dispatch
         self._coll_mixed = self._coll_decode = (0, 0)
         self._ptab_sharding = None
         if self.mesh is not None:
             self.params = shard.shard_tree(
                 self.params, shard.param_pspecs(self.model, self.tp,
                                                 self.pp), self.mesh)
-            self.cache = shard.shard_tree(
-                self.cache, shard.cache_pspecs(self.model, self.tp,
-                                               self.pp), self.mesh)
             self._ptab_sharding = jax.sharding.NamedSharding(
                 self.mesh, jax.sharding.PartitionSpec())
             # the static packed layouts live replicated on the mesh, like
@@ -695,7 +699,7 @@ class ServeEngine:
             m = mask.reshape((1, mask.shape[0]) + (1,) * (n.ndim - 2))
             return jnp.where(m, n, o)
 
-        layers = tree.map(sel, new.layers, scratch.layers)
+        layers = jax.tree.map(sel, new.layers, scratch.layers)
         lengths = jnp.where(mask, new.lengths, scratch.lengths)
         return logits, ModelCache(layers=layers, lengths=lengths)
 
@@ -710,7 +714,7 @@ class ServeEngine:
             idx = (0, slot) + (0,) * (b.ndim - 2)
             return jax.lax.dynamic_update_slice(b, col.astype(b.dtype), idx)
 
-        layers = tree.map(ins, big.layers, small.layers)
+        layers = jax.tree.map(ins, big.layers, small.layers)
         length = jax.lax.dynamic_slice_in_dim(small.lengths, row, 1, axis=0)
         lengths = jax.lax.dynamic_update_slice(big.lengths, length, (slot,))
         return ModelCache(layers=layers, lengths=lengths)
@@ -736,8 +740,8 @@ class ServeEngine:
                     paged_insert_rows, in_axes=(0, 0, None, None))(
                         leaf, small.layers[pos], row, pages)
             else:
-                new_layers[pos] = tree.map(dense_ins, leaf,
-                                           small.layers[pos])
+                new_layers[pos] = jax.tree.map(dense_ins, leaf,
+                                               small.layers[pos])
         length = jax.lax.dynamic_slice_in_dim(small.lengths, row, 1, axis=0)
         lengths = jax.lax.dynamic_update_slice(big.lengths, length, (slot,))
         ptab = jax.lax.dynamic_update_slice(
@@ -754,7 +758,7 @@ class ServeEngine:
             idx = (0, row) + (0,) * (b.ndim - 2)
             return jax.lax.dynamic_update_slice(b, upd, idx)
 
-        layers = tree.map(z, scratch.layers)
+        layers = jax.tree.map(z, scratch.layers)
         lengths = jax.lax.dynamic_update_slice(
             scratch.lengths, jnp.zeros((1,), scratch.lengths.dtype), (row,))
         return ModelCache(layers=layers, lengths=lengths)
@@ -769,7 +773,7 @@ class ServeEngine:
             page = jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1)
             return jax.lax.dynamic_update_slice_in_dim(a, page, dst, axis=1)
 
-        return ModelCache(layers=tree.map(cp, cache.layers),
+        return ModelCache(layers=jax.tree.map(cp, cache.layers),
                           lengths=cache.lengths,
                           page_table=cache.page_table)
 

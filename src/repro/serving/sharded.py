@@ -37,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map, tree
 from ..models import transformer as T
 from ..models.attention import PackedSegs
 from ..models.model import Model, ModelCache
@@ -157,8 +156,8 @@ def param_pspecs(model: Model, tp: int, pp: int):
     """PartitionSpec tree matching ``model.param_axes()``; the embedding
     table is replicated regardless of the vocab rule (see module doc)."""
     rules = _rules(_PARAM_RULES, tp, pp)
-    specs = tree.map(lambda a: _to_pspec(a, rules), model.param_axes(),
-                     is_leaf=_is_axes)
+    specs = jax.tree.map(lambda a: _to_pspec(a, rules),
+                         model.param_axes(), is_leaf=_is_axes)
     if "embed" in specs:
         specs["embed"] = P()
     return specs
@@ -169,16 +168,25 @@ def cache_pspecs(model: Model, tp: int, pp: int):
     kv-heads/layers; lengths + page table replicated, so host page ids
     are valid on every shard)."""
     rules = _rules(_CACHE_RULES, tp, pp)
-    return tree.map(lambda a: _to_pspec(a, rules),
-                    model.cache_axes(), is_leaf=_is_axes)
+    return jax.tree.map(lambda a: _to_pspec(a, rules),
+                        model.cache_axes(), is_leaf=_is_axes)
 
 
 def shard_tree(pytree, pspecs, mesh: Mesh):
     """``device_put`` every leaf with its NamedSharding (replicates the
     host/single-device copy onto the mesh, splitting sharded axes)."""
-    return tree.map(
+    return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
         pytree, pspecs)
+
+
+def init_sharded(init, pspecs, mesh: Mesh):
+    """Run ``init()`` with every output leaf created already split over
+    the mesh by its PartitionSpec: nothing is materialised whole on one
+    device first (a model too large for one chip fits this way)."""
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
+                             is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(init, out_shardings=shardings)()
 
 
 def collective_stats(spec, tp: int, pp: int, t_pack: int, n_segs: int,
@@ -208,24 +216,21 @@ def collective_stats(spec, tp: int, pp: int, t_pack: int, n_segs: int,
     return coll, int(bytes_)
 
 
-def build_sharded_step(model: Model, mesh: Mesh, tp: int, pp: int, *,
-                       max_slots: int, max_q: int, n_decode: int):
-    """The sharded twin of ``ServeEngine._unified_and_sample``: same
-    signature, same (sampled, decode_feed, new_cache) result, one jitted
-    dispatch.  Closes over the static packed profile (max_q, n_decode)
-    exactly like the single-device jits, so nothing retraces."""
+def _sharded_parts(model: Model, mesh: Mesh, tp: int, pp: int, *,
+                   max_q: int, n_decode: int):
+    """The per-rank packed forward shared by :func:`build_sharded_step`
+    and :func:`build_sharded_forward`: ``forward(params, cache, tokens,
+    positions, q_start, q_len, kv_len, seg_ptab) -> (logits, new_cache)``
+    runs inside ``shard_map``; also returns the param/cache spec trees."""
     lspec = local_spec(model.spec, tp)
     # worker-local context: mesh=None (GSPMD constraints are meaningless
     # inside shard_map), tp psums via the named axis
     lctx = model.ctx.with_(spec=lspec, mesh=None,
                            tp_axis=TP_AXIS if tp > 1 else None)
     lmodel = Model(spec=lspec, ctx=lctx)
-    p_specs = param_pspecs(model, tp, pp)
-    c_specs = cache_pspecs(model, tp, pp)
-    rep = P()
 
-    def worker(params, cache, tokens, positions, q_start, q_len, kv_len,
-               seg_ptab, key_data, temps, topks, topps):
+    def forward(params, cache, tokens, positions, q_start, q_len, kv_len,
+                seg_ptab):
         packed = PackedSegs(q_start=q_start, q_len=q_len, kv_len=kv_len,
                             page_table=seg_ptab, max_q=max_q,
                             n_decode=n_decode)
@@ -243,8 +248,8 @@ def build_sharded_step(model: Model, mesh: Mesh, tp: int, pp: int, *,
             # the rank whose stage this is keeps the KV writes and
             # forwards its activation around the ring
             on_stage = jax.lax.axis_index(PP_AXIS) == stage
-            layers = tree.map(lambda n, o: jnp.where(on_stage, n, o),
-                              new_layers, layers)
+            layers = jax.tree.map(lambda n, o: jnp.where(on_stage, n, o),
+                                  new_layers, layers)
             x = jax.lax.ppermute(
                 jnp.where(on_stage, y, x), PP_AXIS,
                 [(i, (i + 1) % pp) for i in range(pp)])
@@ -261,17 +266,49 @@ def build_sharded_step(model: Model, mesh: Mesh, tp: int, pp: int, *,
         lengths = jnp.where(packed.q_len[:b] > 0,
                             packed.kv_len[:b].astype(cache.lengths.dtype),
                             cache.lengths)
+        return logits, ModelCache(layers=layers, lengths=lengths,
+                                  page_table=cache.page_table)
+
+    return forward, param_pspecs(model, tp, pp), cache_pspecs(model, tp, pp)
+
+
+def build_sharded_forward(model: Model, mesh: Mesh, tp: int, pp: int, *,
+                          max_q: int, n_decode: int):
+    """The sharded twin of ``Model.unified_step``: one jitted dispatch
+    ``(params, cache, tokens, positions, q_start, q_len, kv_len, seg_ptab)
+    -> (logits (S, V), new_cache)``, logits replicated.  What a logits
+    comparison against the one-device step runs."""
+    forward, p_specs, c_specs = _sharded_parts(
+        model, mesh, tp, pp, max_q=max_q, n_decode=n_decode)
+    rep = P()
+    return jax.jit(jax.shard_map(
+        forward, mesh=mesh, in_specs=(p_specs, c_specs) + (rep,) * 6,
+        out_specs=(rep, c_specs), check_vma=False), donate_argnums=(1,))
+
+
+def build_sharded_step(model: Model, mesh: Mesh, tp: int, pp: int, *,
+                       max_slots: int, max_q: int, n_decode: int):
+    """The sharded twin of ``ServeEngine._unified_and_sample``: same
+    signature, same (sampled, decode_feed, new_cache) result, one jitted
+    dispatch.  Closes over the static packed profile (max_q, n_decode)
+    exactly like the single-device jits, so nothing retraces."""
+    forward, p_specs, c_specs = _sharded_parts(
+        model, mesh, tp, pp, max_q=max_q, n_decode=n_decode)
+    rep = P()
+
+    def worker(params, cache, tokens, positions, q_start, q_len, kv_len,
+               seg_ptab, key_data, temps, topks, topps):
+        logits, new_cache = forward(params, cache, tokens, positions,
+                                    q_start, q_len, kv_len, seg_ptab)
         step_key = jax.random.wrap_key_data(key_data)
         keys = jax.random.split(step_key, q_len.shape[0])
         toks = sample_slots(logits, keys, temps, topks, topps)
-        new_cache = ModelCache(layers=layers, lengths=lengths,
-                               page_table=cache.page_table)
         return toks, toks[:max_slots], new_cache
 
-    inner = shard_map(
+    inner = jax.shard_map(
         worker, mesh=mesh,
         in_specs=(p_specs, c_specs) + (rep,) * 10,
-        out_specs=(rep, rep, c_specs), check_rep=False)
+        out_specs=(rep, rep, c_specs), check_vma=False)
 
     def stepped(params, cache, tokens, positions, q_start, q_len, kv_len,
                 seg_ptab, step_key, temps, topks, topps):

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 
 
 @dataclass(frozen=True)
@@ -80,8 +79,8 @@ def compressed_psum_grads(grads, mesh, dp_axes=("pod", "data"),
                 out = jax.lax.psum(out, a)
             return out.reshape(gl.shape).astype(gl.dtype)
 
-        fn = shard_map(body, mesh=mesh, in_specs=P(*[None] * g.ndim),
-                       out_specs=P(*[None] * g.ndim), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P(*[None] * g.ndim),
+                           out_specs=P(*[None] * g.ndim), check_vma=False)
         return fn(g)
 
     return jax.tree.map(one, grads)

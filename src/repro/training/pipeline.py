@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 
 
 @dataclass(frozen=True)
@@ -92,5 +91,5 @@ def make_pipelined_fn(layer_fn, mesh, n_stages: int, params_example,
     """
     body = functools.partial(pipeline_forward, layer_fn, n_stages, cfg)
     param_specs = jax.tree.map(lambda _: P(cfg.axis), params_example)
-    return shard_map(body, mesh=mesh, in_specs=(param_specs, P()),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(param_specs, P()),
+                         out_specs=P(), check_vma=False)

@@ -12,7 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import tree
 from repro.models import build_model
 from repro.models.model import ModelCache
 from repro.serving import (DisaggCluster, DisaggClusterConfig, EngineConfig,
@@ -136,7 +135,8 @@ def test_poisoned_page_corruption_probe(served):
         def scribble(a):
             return a.at[:, ids].set(jnp.asarray(1e3, a.dtype))
 
-        pre.cache = ModelCache(layers=tree.map(scribble, pre.cache.layers),
+        pre.cache = ModelCache(layers=jax.tree.map(scribble,
+                                                   pre.cache.layers),
                                lengths=pre.cache.lengths,
                                page_table=pre.cache.page_table)
         poisoned.append(mig.req.rid)

@@ -289,6 +289,65 @@ def test_ragged_prefill_chunk_matches_dense_chunk():
                                atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("attn_impl,want", [
+    ("auto", ("gather", False)),  # the CPU has no TPU: the oracle
+    ("gather", ("gather", False)),
+    ("pallas", ("pallas", True)),  # explicit: the kernel, interpreted
+])
+def test_paged_kernel_choice_on_cpu(attn_impl, want):
+    from repro.models.common import ModelContext
+    from conftest import tiny_dense_spec
+    assert jax.default_backend() == "cpu"
+    ctx = ModelContext(spec=tiny_dense_spec(), attn_impl=attn_impl)
+    assert ctx.paged_kernel() == want
+
+
+@pytest.mark.parametrize("attn_impl,kernel_calls", [("auto", 0),
+                                                     ("pallas", 2)])
+def test_unified_step_reaches_kernel_only_when_chosen(monkeypatch, attn_impl,
+                                                      kernel_calls):
+    """On the CPU "auto" serves the packed step with the gather oracle and
+    never traces the ragged kernel; an explicit "pallas" reaches it (once
+    per sub-batch of the decode/prefill split), with the same logits."""
+    from repro.kernels import ragged_attention
+    from repro.models import build_model
+    from repro.models.attention import PackedSegs
+    from conftest import tiny_dense_spec
+
+    real = ragged_attention.pallas_ragged_paged_attention
+    calls = []
+
+    def spy(*a, **k):
+        calls.append(k["interpret"])
+        return real(*a, **k)
+    monkeypatch.setattr(ragged_attention, "pallas_ragged_paged_attention",
+                        spy)
+    spec = tiny_dense_spec(n_layers=1)
+
+    def logits(impl):
+        model = build_model(spec, param_dtype=jnp.float32,
+                            compute_dtype=jnp.float32, cache_layout="paged",
+                            kv_page_size=4, attn_impl=impl)
+        params = model.init(jax.random.key(0))
+        cache = model.init_cache(2, 16, layout="paged", n_pages=9)
+        # one decode slot (idle) and one 6-token prefill chunk
+        packed = PackedSegs(q_start=jnp.asarray([0, 1], jnp.int32),
+                            q_len=jnp.asarray([0, 6], jnp.int32),
+                            kv_len=jnp.asarray([0, 6], jnp.int32),
+                            page_table=jnp.asarray([[0] * 4, [1, 2, 0, 0]],
+                                                   jnp.int32),
+                            max_q=8, n_decode=1)
+        tokens = jnp.arange(9, dtype=jnp.int32) % spec.vocab
+        pos = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                               jnp.arange(8, dtype=jnp.int32)])
+        out, _ = model.unified_step(params, cache, tokens, pos, packed)
+        return np.asarray(out)[1]
+
+    got = logits(attn_impl)
+    assert calls == [True] * kernel_calls
+    np.testing.assert_allclose(got, logits("gather"), atol=1e-5, rtol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # RWKV6 scan kernel + chunked recurrence
 # ---------------------------------------------------------------------------

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import tree
 from repro.core import Optimizations, Workload
 from repro.core.stages import concurrency_from_kv_budget
 from repro.models import build_model
@@ -356,8 +355,8 @@ def test_cow_fork_isolation(served):
     # corrupt the shared tail page on device; r2 only reads its fork
     poison = dataclasses.replace(
         eng.cache,
-        layers=tree.map(lambda a: a.at[:, shared[1]].set(1e9),
-                        eng.cache.layers))
+        layers=jax.tree.map(lambda a: a.at[:, shared[1]].set(1e9),
+                            eng.cache.layers))
     assert isinstance(poison, ModelCache)
     eng.cache = poison
     while r2.state != "done":

@@ -276,3 +276,50 @@ def test_page_table_bounds_and_shard_geometry():
             assert sh.data.shape[2] == k.shape[2] // 2  # kv heads / tp
         print("OK", k.shape, "->", tuple(sh.data.shape))
     """)
+
+
+def test_sharded_forward_logits_match_one_device():
+    """Params and pools created already split over the mesh hold the same
+    values as the one-device ones, and the sharded packed forward's
+    logits match ``Model.unified_step`` at tp=2 and tp=2 x pp=2."""
+    _mesh_run(4, """
+        import numpy as np
+        from repro.models.attention import PackedSegs
+        from repro.serving import sharded as shard
+
+        fmodel = build_model(spec, param_dtype=jnp.float32,
+                             compute_dtype=jnp.float32,
+                             cache_layout="paged", kv_page_size=8)
+
+        def init_cache():
+            return fmodel.init_cache(2, 32, layout="paged", n_pages=9)
+
+        # two idle decode slots, then prefill rows of 4 and 3 tokens
+        i32 = lambda x: jnp.asarray(x, jnp.int32)
+        args = (i32([0, 0, 5, 6, 7, 8, 9, 10, 11, 0]),
+                i32([0, 0, 0, 1, 2, 3, 0, 1, 2, 0]),
+                i32([0, 1, 2, 6]), i32([0, 0, 4, 3]), i32([0, 0, 4, 3]),
+                i32([[0] * 4, [0] * 4, [1, 0, 0, 0], [2, 0, 0, 0]]))
+
+        def one(p, c, tok, pos, qs, ql, kl, pt):
+            packed = PackedSegs(q_start=qs, q_len=ql, kv_len=kl,
+                                page_table=pt, max_q=4, n_decode=2)
+            return fmodel.unified_step(p, c, tok, pos, packed)
+        want, _ = jax.jit(one)(params, init_cache(), *args)
+        for tp, pp in ((2, 1), (2, 2)):
+            mesh = shard.make_engine_mesh(tp, pp)
+            p = shard.init_sharded(lambda: fmodel.init(jax.random.key(0)),
+                                   shard.param_pspecs(fmodel, tp, pp), mesh)
+            for a, b in zip(jax.tree.leaves(p), jax.tree.leaves(params)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            c = shard.init_sharded(init_cache,
+                                   shard.cache_pspecs(fmodel, tp, pp), mesh)
+            assert len(c.layers["pos0"].k.addressable_shards) == tp * pp
+            fwd = shard.build_sharded_forward(fmodel, mesh, tp, pp, max_q=4,
+                                              n_decode=2)
+            got, _ = fwd(p, c, *args)
+            np.testing.assert_allclose(np.asarray(got)[2:],
+                                       np.asarray(want)[2:],
+                                       atol=1e-5, rtol=1e-5)
+        print("OK")
+    """)

@@ -192,6 +192,7 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, seg_page_table, q_start,
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="ragged_paged_attention",
         out_shape=jax.ShapeDtypeStruct((hkv * s_count, max_q, g, d), q.dtype),
         interpret=interpret,
     )(jnp.asarray(seg_page_table, jnp.int32),

@@ -318,11 +318,12 @@ def _paged_attention(spec: ModelSpec, ctx: ModelContext, cache:
                                               mode="drop",
                                               unique_indices=False)
 
-    kc, vc = scat(cache.k, k_store), scat(cache.v, v_store)
-    new_cache = PagedAttnCache(
-        k=kc, v=vc,
-        k_scale=scat(cache.k_scale, k_sc) if quant else None,
-        v_scale=scat(cache.v_scale, v_sc) if quant else None)
+    with jax.named_scope("kv_write"):
+        kc, vc = scat(cache.k, k_store), scat(cache.v, v_store)
+        new_cache = PagedAttnCache(
+            k=kc, v=vc,
+            k_scale=scat(cache.k_scale, k_sc) if quant else None,
+            v_scale=scat(cache.v_scale, v_sc) if quant else None)
 
     impl, interpret = ctx.paged_kernel()
     if impl == "pallas" and not quant:
@@ -379,11 +380,12 @@ def _packed_paged_attention(spec: ModelSpec, ctx: ModelContext,
                                               mode="drop",
                                               unique_indices=False)
 
-    kc, vc = scat(cache.k, k_store), scat(cache.v, v_store)
-    new_cache = PagedAttnCache(
-        k=kc, v=vc,
-        k_scale=scat(cache.k_scale, k_sc) if quant else None,
-        v_scale=scat(cache.v_scale, v_sc) if quant else None)
+    with jax.named_scope("kv_write"):
+        kc, vc = scat(cache.k, k_store), scat(cache.v, v_store)
+        new_cache = PagedAttnCache(
+            k=kc, v=vc,
+            k_scale=scat(cache.k_scale, k_sc) if quant else None,
+            v_scale=scat(cache.v_scale, v_sc) if quant else None)
 
     # the ragged kernel takes no scale operands: int8 KV keeps the oracle
     impl, interpret = ctx.paged_kernel()

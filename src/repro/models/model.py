@@ -113,15 +113,18 @@ class Model:
         return params["lm_head"]
 
     def _logits(self, params, h):
-        h = rms_norm(h, params["final_norm"])
-        logits = h @ self._head_w(params)
-        if self.ctx.tp_axis is not None and not self.spec.tied_embeddings:
-            # vocab-sharded untied head: each rank holds (d, V/tp) — the
-            # step's single logits gather (tied heads stay replicated
-            # because the embedding table must serve full-vocab lookups)
-            logits = jax.lax.all_gather(logits, self.ctx.tp_axis,
-                                        axis=logits.ndim - 1, tiled=True)
-        return self.ctx.shard(logits, "batch", "seq", "act_vocab")
+        with jax.named_scope("head"):
+            h = rms_norm(h, params["final_norm"])
+            logits = h @ self._head_w(params)
+            if self.ctx.tp_axis is not None \
+                    and not self.spec.tied_embeddings:
+                # vocab-sharded untied head: each rank holds (d, V/tp) —
+                # the step's single logits gather (tied heads stay
+                # replicated because the embedding table must serve
+                # full-vocab lookups)
+                logits = jax.lax.all_gather(logits, self.ctx.tp_axis,
+                                            axis=logits.ndim - 1, tiled=True)
+            return self.ctx.shard(logits, "batch", "seq", "act_vocab")
 
     # -- training / encoder forward ---------------------------------------------
     def forward(self, params, tokens=None, *, embeds=None,

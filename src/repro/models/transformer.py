@@ -106,10 +106,11 @@ def _apply_one(spec: ModelSpec, ctx: ModelContext, cls: LayerClass,
                params: dict, x, positions, cache, lengths,
                page_table=None, packed=None):
     if cls.kind == "attn":
-        y, new_cache = attention_block(spec, ctx, params["mixer"], x,
-                                       positions, cache, lengths,
-                                       page_table=page_table,
-                                       packed=packed)
+        with jax.named_scope("attn"):
+            y, new_cache = attention_block(spec, ctx, params["mixer"], x,
+                                           positions, cache, lengths,
+                                           page_table=page_table,
+                                           packed=packed)
         x = x + y
     elif packed is not None:
         raise NotImplementedError(
@@ -124,7 +125,8 @@ def _apply_one(spec: ModelSpec, ctx: ModelContext, cls: LayerClass,
         if cls.is_moe:
             x = x + moe_block(spec, ctx, params["ffn"], x)
         else:
-            x = x + mlp_block(spec, ctx, params["ffn"], x)
+            with jax.named_scope("mlp"):
+                x = x + mlp_block(spec, ctx, params["ffn"], x)
     x = ctx.shard(x, "batch", "seq_res", "act_embed")
     return x, new_cache
 

@@ -117,8 +117,8 @@ class Request:
     slot: int = -1
     n_cached: int = 0  # prompt tokens served from shared prefix-cache pages
     ttft_steps: int = 0  # engine steps until first token (TTFT proxy)
-    tpot_steps: int = 0
     submit_t: float = 0.0  # wall-clock timestamps (perf_counter)
+    admit_t: float = 0.0  # left the queue (first admission only)
     first_token_t: float = 0.0
     finish_t: float = 0.0
 
@@ -141,7 +141,7 @@ class EngineConfig:
     chunk_size: int = 128
     decode_priority: bool = True  # decode before prefill chunks (SLO order)
     prefill_rows: int = 2  # concurrent chunked prefills (scratch rows)
-    record_step_log: bool = False  # keep a per-step occupancy trace
+    record_step_log: bool = False  # keep a StepRecord per step
     #: KV-cache layout: "dense" reserves max_slots x max_seq tokens per
     #: layer; "paged" keeps an n_pages pool + page-table indirection
     cache_layout: str = "dense"
@@ -194,6 +194,27 @@ class EngineConfig:
 
 
 @dataclass
+class StepRecord:
+    """What one ``step()`` did, kept in ``EngineMetrics.step_log`` when
+    ``record_step_log`` is on.  Times are ``perf_counter``.  The packing
+    fields are filled by the token-packed steps (unified, speculative);
+    the two-dispatch path leaves them empty."""
+
+    step: int
+    t0: float
+    t1: float = 0.0
+    mixed: bool = False  # the mixed decode+prefill profile ran
+    rows_packed: int = 0  # token rows of the profile that ran
+    rows_live: int = 0  # of those, rows holding a live token (sum q_len)
+    decode: list = field(default_factory=list)  # live decode kv_lens
+    prefill: list = field(default_factory=list)  # [(q_len, kv_len)]
+    sampled: int = 0  # segments whose sampled token is used
+    pages_in_use: int = 0  # after the step (paged layout)
+    preempted: int = 0  # preemptions during the step
+    admitted: list = field(default_factory=list)  # rids admitted
+
+
+@dataclass
 class EngineMetrics:
     """Wall-clock + step-level serving metrics."""
 
@@ -212,7 +233,7 @@ class EngineMetrics:
     end_t: float = 0.0
     occupancy_sum: float = 0.0  # sum over steps of active/max_slots
     steps: int = 0
-    step_log: list = field(default_factory=list)  # (step, active, prefill, queued)
+    step_log: list = field(default_factory=list)  # StepRecord per step
     # -- KV capacity counters (both layouts) --------------------------------
     peak_active: int = 0  # max concurrent decode slots (measured concurrency)
     peak_inflight: int = 0  # max active + in-flight prefills
@@ -437,6 +458,7 @@ class ServeEngine:
         self.export_fn = None
         self.steps = 0
         self.metrics = EngineMetrics()
+        self._rec: StepRecord | None = None  # this step's, when recording
 
         # (pp, tp) device mesh of the sharded unified step (None: one
         # device); the paged pools are created already split over it
@@ -665,8 +687,9 @@ class ServeEngine:
         (B, 1) next-step feed stays resident on device (reusing the
         donated input buffer), so steady-state decode re-uploads nothing."""
         logits, new_cache = self.model.decode_step(params, cache, tokens)
-        keys = jax.random.split(step_key, self.cfg.max_slots)
-        toks = sample_slots(logits, keys, temps, topks, topps)
+        with jax.named_scope("sample"):
+            keys = jax.random.split(step_key, self.cfg.max_slots)
+            toks = sample_slots(logits, keys, temps, topks, topps)
         return toks, toks[:, None], new_cache
 
     def _unified_and_sample(self, params, cache: ModelCache, tokens,
@@ -683,8 +706,9 @@ class ServeEngine:
                             n_decode=n_decode)
         logits, new_cache = self.model.unified_step(params, cache, tokens,
                                                     positions, packed)
-        keys = jax.random.split(step_key, q_len.shape[0])
-        toks = sample_slots(logits, keys, temps, topks, topps)
+        with jax.named_scope("sample"):
+            keys = jax.random.split(step_key, q_len.shape[0])
+            toks = sample_slots(logits, keys, temps, topks, topps)
         # the first max_slots samples are next step's decode feed: keep a
         # device-resident copy so steady-state decode re-uploads nothing
         return toks, toks[:self.cfg.max_slots], new_cache
@@ -841,6 +865,10 @@ class ServeEngine:
             # uncached suffix is ever computed
             self._prefill_pos[row] = req.n_cached
             req.state = "prefill"
+            if not req.admit_t:  # a resumed request keeps its first
+                req.admit_t = time.perf_counter()
+            if self._rec is not None:
+                self._rec.admitted.append(req.rid)
             if not self.unified:  # unified prefill has no scratch to reset
                 self.scratch = self._jit_reset_row(self.scratch,
                                                    self._dev_i32(row))
@@ -1190,7 +1218,6 @@ class ServeEngine:
         for slot, req in list(self.active.items()):
             tok = int(toks[slot])
             req.output.append(tok)
-            req.tpot_steps += 1
             self._lengths[slot] += 1
             self.metrics.generated_tokens += 1
             done = (len(req.output) >= req.max_new_tokens
@@ -1279,15 +1306,19 @@ class ServeEngine:
                 f"(max_pages={self.max_pages} x page_size="
                 f"{self.cfg.page_size})")
 
-    def _unified_step(self) -> None:
+    def _unified_step(self):
         """The whole iteration in ONE jitted dispatch: all active slots'
         decode tokens and all in-flight prompts' current chunks packed
         into the fixed ragged layout, prefill K/V written directly to
         pages, every segment sampled on device.  The sampled (S,) vector
-        is the step's single device->host transfer."""
-        self._grow_pages()
+        is the step's single device->host transfer.  Returns the step's
+        commit (the host bookkeeping on the pulled tokens), or None when
+        no request is left to step."""
+        span = jax.profiler.TraceAnnotation
+        with span("engine.pages"):
+            self._grow_pages()
         if not (self.active or self._prefills):
-            return
+            return None
         nslots, csize = self.cfg.max_slots, self.cfg.chunk_size
         # two static packed profiles (one compiled program each): the
         # decode-only layout (T = max_slots) when no prefill is in flight,
@@ -1296,77 +1327,103 @@ class ServeEngine:
         mixed = bool(self._prefills)
         n_segs, t_pack = (self.n_segs, self.t_pack) if mixed \
             else (nslots, nslots)
-        positions = np.zeros((t_pack,), np.int32)
-        q_len = np.zeros((n_segs,), np.int32)
-        kv_len = np.zeros((n_segs,), np.int32)
-        # decode segments: slot s's next token at packed offset s
-        for slot in self.active:
-            positions[slot] = self._lengths[slot]
-            q_len[slot] = 1
-            kv_len[slot] = self._lengths[slot] + 1
         widths: dict[int, int] = {}
-        if mixed:
-            tokens = np.zeros((t_pack,), np.int32)
-            tokens[:nslots] = self._tokens[:, 0]
-            seg_ptab = np.zeros((n_segs, self.max_pages), np.int32)
-            seg_ptab[:nslots] = self._ptab
-            temps = np.zeros((n_segs,), np.float32)
-            topks = np.zeros((n_segs,), np.int32)
-            topps = np.ones((n_segs,), np.float32)
-            temps[:nslots] = self._temps
-            topks[:nslots] = self._topks
-            topps[:nslots] = self._topps
-            # prefill segments: row r's current chunk at nslots + r * csize
-            for row, req in self._prefills.items():
-                src = self._src(req)
-                self._pack_guard(req, len(src))
-                lo = self._prefill_pos[row]
-                w = min(csize, len(src) - lo)
-                seg, qs = nslots + row, nslots + row * csize
-                tokens[qs:qs + w] = src[lo:lo + w]
-                positions[qs:qs + w] = np.arange(lo, lo + w)
-                q_len[seg] = w
-                kv_len[seg] = lo + w
-                seg_ptab[seg] = self._ptab_row(req.rid)
-                widths[row] = w
-                if lo + w >= len(src):  # completes: sample with its config
-                    s = req.sampling
-                    temps[seg] = s.temperature
-                    topks[seg] = s.top_k
-                    topps[seg] = s.top_p
-            fn, seg_start = self._jit_unified, self._seg_start_dev
-            tokens_dev = self._up(tokens)
-            ptab_dev = self._up(seg_ptab)
-            sampling_dev = (self._up(temps), self._up(topks),
-                            self._up(topps))
-        else:
-            # decode-only steady state: tokens, sampling params and the
-            # slot page table all live on device already — nothing but
-            # positions/lengths (which advance every step) is uploaded
-            fn, seg_start = self._jit_unified_decode, \
-                self._seg_start_decode_dev
-            tokens_dev = self._dev_utokens
-            if tokens_dev is None:
-                tokens_dev = self._up(self._tokens[:, 0])
-            if self._dev_ptab is None:
-                self._dev_ptab = self._up(self._ptab)
-            ptab_dev = self._dev_ptab
-            if self._dev_sampling is None:
-                self._dev_sampling = (self._up(self._temps),
-                                      self._up(self._topks),
-                                      self._up(self._topps))
-            sampling_dev = self._dev_sampling
-        self.rng, step_key = jax.random.split(self.rng)
-        if self._ptab_sharding is not None:
-            # the split key lives on device 0: replicate it explicitly so
-            # the dispatch stays transfer-free under the guard
-            step_key = jax.device_put(step_key, self._ptab_sharding)
-        sampled, self._dev_utokens, self.cache = fn(
-            self.params, self.cache, tokens_dev, self._up(positions),
-            seg_start, self._up(q_len), self._up(kv_len), ptab_dev,
-            step_key, *sampling_dev)
-        # the step's only device->host transfer: the (S,) sampled tokens
-        toks = jax.device_get(sampled)
+        with span("engine.pack"):
+            positions = np.zeros((t_pack,), np.int32)
+            q_len = np.zeros((n_segs,), np.int32)
+            kv_len = np.zeros((n_segs,), np.int32)
+            # decode segments: slot s's next token at packed offset s
+            for slot in self.active:
+                positions[slot] = self._lengths[slot]
+                q_len[slot] = 1
+                kv_len[slot] = self._lengths[slot] + 1
+            completing = 0
+            if mixed:
+                tokens = np.zeros((t_pack,), np.int32)
+                tokens[:nslots] = self._tokens[:, 0]
+                seg_ptab = np.zeros((n_segs, self.max_pages), np.int32)
+                seg_ptab[:nslots] = self._ptab
+                temps = np.zeros((n_segs,), np.float32)
+                topks = np.zeros((n_segs,), np.int32)
+                topps = np.ones((n_segs,), np.float32)
+                temps[:nslots] = self._temps
+                topks[:nslots] = self._topks
+                topps[:nslots] = self._topps
+                # prefill segments: row r's current chunk at
+                # nslots + r * csize
+                for row, req in self._prefills.items():
+                    src = self._src(req)
+                    self._pack_guard(req, len(src))
+                    lo = self._prefill_pos[row]
+                    w = min(csize, len(src) - lo)
+                    seg, qs = nslots + row, nslots + row * csize
+                    tokens[qs:qs + w] = src[lo:lo + w]
+                    positions[qs:qs + w] = np.arange(lo, lo + w)
+                    q_len[seg] = w
+                    kv_len[seg] = lo + w
+                    seg_ptab[seg] = self._ptab_row(req.rid)
+                    widths[row] = w
+                    if lo + w >= len(src):  # completes: its config samples
+                        s = req.sampling
+                        temps[seg] = s.temperature
+                        topks[seg] = s.top_k
+                        topps[seg] = s.top_p
+                        completing += 1
+            rec = self._rec
+            if rec is not None:
+                rec.mixed, rec.rows_packed = mixed, t_pack
+                rec.rows_live = int(q_len.sum())
+                rec.decode = [int(kv_len[s]) for s in self.active]
+                rec.prefill = [(w, int(kv_len[nslots + r]))
+                               for r, w in widths.items()]
+                rec.sampled = len(self.active) + completing
+        with span("engine.upload"):
+            if mixed:
+                fn, seg_start = self._jit_unified, self._seg_start_dev
+                tokens_dev = self._up(tokens)
+                ptab_dev = self._up(seg_ptab)
+                sampling_dev = (self._up(temps), self._up(topks),
+                                self._up(topps))
+            else:
+                # decode-only steady state: tokens, sampling params and
+                # the slot page table all live on device already —
+                # nothing but positions/lengths (which advance every
+                # step) is uploaded
+                fn, seg_start = self._jit_unified_decode, \
+                    self._seg_start_decode_dev
+                tokens_dev = self._dev_utokens
+                if tokens_dev is None:
+                    tokens_dev = self._up(self._tokens[:, 0])
+                if self._dev_ptab is None:
+                    self._dev_ptab = self._up(self._ptab)
+                ptab_dev = self._dev_ptab
+                if self._dev_sampling is None:
+                    self._dev_sampling = (self._up(self._temps),
+                                          self._up(self._topks),
+                                          self._up(self._topps))
+                sampling_dev = self._dev_sampling
+            segs_dev = (self._up(positions), seg_start, self._up(q_len),
+                        self._up(kv_len))
+            self.rng, step_key = jax.random.split(self.rng)
+            if self._ptab_sharding is not None:
+                # the split key lives on device 0: replicate it
+                # explicitly so the dispatch stays transfer-free under
+                # the guard
+                step_key = jax.device_put(step_key, self._ptab_sharding)
+        with span("engine.dispatch"):
+            sampled, self._dev_utokens, self.cache = fn(
+                self.params, self.cache, tokens_dev, *segs_dev, ptab_dev,
+                step_key, *sampling_dev)
+        with span("engine.pull"):
+            # the step's only device->host transfer: the (S,) tokens
+            toks = jax.device_get(sampled)
+        return functools.partial(self._unified_commit, toks, mixed, widths)
+
+    def _unified_commit(self, toks, mixed: bool, widths: dict) -> None:
+        """Host bookkeeping of a unified step on its pulled tokens: finish
+        and advance decode slots, advance prefill rows, promote the
+        completing ones."""
+        nslots = self.cfg.max_slots
         self.metrics.dispatches += 1
         self.metrics.transfers_d2h += 1
         coll, coll_bytes = self._coll_mixed if mixed else self._coll_decode
@@ -1397,7 +1454,7 @@ class ServeEngine:
                                   install)
 
     # -- speculative token-packed step ----------------------------------------
-    def _spec_step(self) -> None:
+    def _spec_step(self):
         """The unified step with speculation: every active slot packs a
         K+1-token verify window (committed feed + the draft's K proposals,
         causal within the segment); the draft catch-up, the K-step propose
@@ -1407,77 +1464,106 @@ class ServeEngine:
         Rollback of rejected tokens is pure length bookkeeping on both the
         host mirrors and the device ``cache.lengths`` (stale K/V past the
         accepted frontier is masked by kv_len until overwritten — the
-        preemption-recompute invariant)."""
-        self._grow_pages()
+        preemption-recompute invariant).  Returns the step's commit, or
+        None when no request is left to step."""
+        span = jax.profiler.TraceAnnotation
+        with span("engine.pages"):
+            self._grow_pages()
         if not (self.active or self._prefills):
-            return
+            return None
         spec = self.speculator
         nslots, csize = self.cfg.max_slots, self.cfg.chunk_size
         rows = self.cfg.prefill_rows
         mixed = bool(self._prefills)
-        n_samp = nslots + rows if mixed else nslots
-        feed = np.zeros((nslots,), np.int32)
-        d_feed = np.zeros((nslots, 2), np.int32)
-        lengths = np.zeros((nslots,), np.int32)
-        gaps = np.zeros((nslots,), np.int32)
-        win = np.zeros((nslots,), np.int32)
-        temps = np.zeros((n_samp,), np.float32)
-        topks = np.zeros((n_samp,), np.int32)
-        topps = np.ones((n_samp,), np.float32)
-        temps[:nslots] = self._temps
-        topks[:nslots] = self._topks
-        topps[:nslots] = self._topps
-        for slot, req in self.active.items():
-            src = self._src(req)
-            sl = int(self._lengths[slot])
-            g, tail = spec.catch_up(slot, src)
-            if not 1 <= g <= 2:  # the draft frontier invariant
-                raise AssertionError(
-                    f"slot {slot}: draft gap {g} outside {{1, 2}} "
-                    f"(d_len={int(spec.d_lens[slot])}, len={sl})")
-            feed[slot] = src[-1]
-            d_feed[slot, :g] = tail
-            lengths[slot] = sl
-            gaps[slot] = g
-            win[slot] = min(spec.k + 1, self.cfg.max_seq - sl)
         widths: dict[int, int] = {}
-        if mixed:
-            pre_tokens = np.zeros((rows * csize,), np.int32)
-            pre_positions = np.zeros((rows * csize,), np.int32)
-            pre_q_len = np.zeros((rows,), np.int32)
-            pre_kv_len = np.zeros((rows,), np.int32)
-            pre_ptab = np.zeros((rows, self.max_pages), np.int32)
-            for row, req in self._prefills.items():
+        with span("engine.pack"):
+            n_samp = nslots + rows if mixed else nslots
+            feed = np.zeros((nslots,), np.int32)
+            d_feed = np.zeros((nslots, 2), np.int32)
+            lengths = np.zeros((nslots,), np.int32)
+            gaps = np.zeros((nslots,), np.int32)
+            win = np.zeros((nslots,), np.int32)
+            temps = np.zeros((n_samp,), np.float32)
+            topks = np.zeros((n_samp,), np.int32)
+            topps = np.ones((n_samp,), np.float32)
+            temps[:nslots] = self._temps
+            topks[:nslots] = self._topks
+            topps[:nslots] = self._topps
+            for slot, req in self.active.items():
                 src = self._src(req)
-                self._pack_guard(req, len(src))
-                lo = self._prefill_pos[row]
-                w = min(csize, len(src) - lo)
-                qs = row * csize
-                pre_tokens[qs:qs + w] = src[lo:lo + w]
-                pre_positions[qs:qs + w] = np.arange(lo, lo + w)
-                pre_q_len[row] = w
-                pre_kv_len[row] = lo + w
-                pre_ptab[row] = self._ptab_row(req.rid)
-                widths[row] = w
-                if lo + w >= len(src):  # completes: sample with its config
-                    s = req.sampling
-                    temps[nslots + row] = s.temperature
-                    topks[nslots + row] = s.top_k
-                    topps[nslots + row] = s.top_p
-        else:
-            pre_tokens = pre_positions = pre_q_len = pre_kv_len = \
-                pre_ptab = None
-        if self._dev_ptab is None:
-            self._dev_ptab = self._up(self._ptab)
-        self.rng, step_key = jax.random.split(self.rng)
-        self.cache, pulled = spec.dispatch(
-            self.params, self.cache, feed, d_feed, lengths, gaps, win,
-            self._dev_ptab, pre_tokens, pre_positions, pre_q_len,
-            pre_kv_len, pre_ptab, step_key, temps, topks, topps,
-            mixed=mixed)
-        # the step's only device->host transfer: accepted tokens, per-slot
-        # counts, and (mixed) the completing prefills' first tokens
-        out_toks, n_emit, pre_sampled = jax.device_get(pulled)
+                sl = int(self._lengths[slot])
+                g, tail = spec.catch_up(slot, src)
+                if not 1 <= g <= 2:  # the draft frontier invariant
+                    raise AssertionError(
+                        f"slot {slot}: draft gap {g} outside {{1, 2}} "
+                        f"(d_len={int(spec.d_lens[slot])}, len={sl})")
+                feed[slot] = src[-1]
+                d_feed[slot, :g] = tail
+                lengths[slot] = sl
+                gaps[slot] = g
+                win[slot] = min(spec.k + 1, self.cfg.max_seq - sl)
+            completing = 0
+            pre = [None] * 5  # tokens, positions, q_len, kv_len, ptab
+            if mixed:
+                pre = [np.zeros((rows * csize,), np.int32),
+                       np.zeros((rows * csize,), np.int32),
+                       np.zeros((rows,), np.int32),
+                       np.zeros((rows,), np.int32),
+                       np.zeros((rows, self.max_pages), np.int32)]
+                pre_tokens, pre_positions, pre_q_len, pre_kv_len, \
+                    pre_ptab = pre
+                for row, req in self._prefills.items():
+                    src = self._src(req)
+                    self._pack_guard(req, len(src))
+                    lo = self._prefill_pos[row]
+                    w = min(csize, len(src) - lo)
+                    qs = row * csize
+                    pre_tokens[qs:qs + w] = src[lo:lo + w]
+                    pre_positions[qs:qs + w] = np.arange(lo, lo + w)
+                    pre_q_len[row] = w
+                    pre_kv_len[row] = lo + w
+                    pre_ptab[row] = self._ptab_row(req.rid)
+                    widths[row] = w
+                    if lo + w >= len(src):  # completes: its config samples
+                        s = req.sampling
+                        temps[nslots + row] = s.temperature
+                        topks[nslots + row] = s.top_k
+                        topps[nslots + row] = s.top_p
+                        completing += 1
+            rec = self._rec
+            if rec is not None:
+                rec.mixed = mixed
+                rec.rows_packed = nslots * (spec.k + 1) \
+                    + (rows * csize if mixed else 0)
+                rec.rows_live = int(win.sum()) + sum(widths.values())
+                rec.decode = [int(lengths[s] + win[s]) for s in self.active]
+                rec.prefill = [(w, int(pre[3][r]))
+                               for r, w in widths.items()]
+                rec.sampled = len(self.active) + completing
+        with span("engine.upload"):
+            if self._dev_ptab is None:
+                self._dev_ptab = self._up(self._ptab)
+            slots_dev = [self._up(a) for a in (feed, d_feed, lengths, gaps,
+                                                win)]
+            pre_dev = [None if a is None else self._up(a) for a in pre]
+            sampling_dev = [self._up(a) for a in (temps, topks, topps)]
+            self.rng, step_key = jax.random.split(self.rng)
+        with span("engine.dispatch"):
+            self.cache, pulled = spec.dispatch(
+                self.params, self.cache, *slots_dev, self._dev_ptab,
+                *pre_dev, step_key, *sampling_dev, mixed=mixed)
+        with span("engine.pull"):
+            # the step's only device->host transfer: accepted tokens,
+            # per-slot counts, and (mixed) the completing prefills' first
+            # tokens
+            out_toks, n_emit, pre_sampled = jax.device_get(pulled)
+        return functools.partial(self._spec_commit, out_toks, n_emit,
+                                 pre_sampled, win, widths)
+
+    def _spec_commit(self, out_toks, n_emit, pre_sampled, win,
+                     widths: dict) -> None:
+        """Host bookkeeping of a speculative step on its pulled tokens."""
+        spec = self.speculator
         self.metrics.dispatches += 1
         self.metrics.transfers_d2h += 1
         now = time.perf_counter()
@@ -1529,7 +1615,6 @@ class ServeEngine:
             tally = m.spec_by_slot.setdefault(slot, [0, 0])
             tally[0] += emit - 1
             tally[1] += w - 1
-            req.tpot_steps += 1
             done = False
             committed = 0
             for j in range(emit):
@@ -1562,23 +1647,45 @@ class ServeEngine:
     def step(self) -> None:
         """One engine iteration: a decode step for all active slots plus a
         prefill chunk for every in-flight prompt (decode-priority order) —
-        or, with ``unified=True``, both packed into one dispatch."""
+        or, with ``unified=True``, both packed into one dispatch.
+
+        The step's phases are ``jax.profiler.TraceAnnotation`` spans
+        (``engine.step`` around all of it, ``engine.admit``; on the packed
+        paths ``engine.pages``, ``engine.pack``, ``engine.upload``,
+        ``engine.dispatch``, ``engine.pull`` and ``engine.commit``), so a
+        profiler trace places the host's time beside the device's."""
         if self.metrics.start_t == 0.0:
             self.metrics.start_t = time.perf_counter()
         self.steps += 1
         self.metrics.steps += 1
-        self._admit()
-        with self._step_guard():
-            if self.speculator is not None:
-                self._spec_step()
-            elif self.unified:
-                self._unified_step()
-            elif self.cfg.decode_priority:
-                self._decode_step()
-                self._prefill_step()
-            else:
-                self._prefill_step()
-                self._decode_step()
+        if self.cfg.record_step_log:
+            self._rec = StepRecord(step=self.steps, t0=time.perf_counter())
+        preempted = self.metrics.preemptions
+        span = jax.profiler.TraceAnnotation
+        with span("engine.step", step=self.steps):
+            with span("engine.admit"):
+                self._admit()
+            with self._step_guard():
+                commit = None
+                if self.speculator is not None:
+                    commit = self._spec_step()
+                elif self.unified:
+                    commit = self._unified_step()
+                elif self.cfg.decode_priority:
+                    self._decode_step()
+                    self._prefill_step()
+                else:
+                    self._prefill_step()
+                    self._decode_step()
+                with (span("engine.commit") if self.unified
+                      else contextlib.nullcontext()):
+                    if commit is not None:
+                        commit()
+                    self._account_step(preempted)
+
+    def _account_step(self, preempted: int) -> None:
+        """End-of-step audits and metric updates (``preempted``: the
+        preemption count when the step began)."""
         if self.debug_guards:
             self._assert_no_retrace()
             if self.paged:
@@ -1606,10 +1713,13 @@ class ServeEngine:
             cap_tokens = self.cfg.max_slots * self.cfg.max_seq
         m.kv_util_sum += used / cap_tokens
         m.kv_used_tokens_peak = max(m.kv_used_tokens_peak, used)
-        if self.cfg.record_step_log:
-            self.metrics.step_log.append(
-                (self.steps, len(self.active), len(self._prefills),
-                 len(self.queue)))
+        rec, self._rec = self._rec, None
+        if rec is not None:
+            rec.preempted = m.preemptions - preempted
+            if self.paged:
+                rec.pages_in_use = self.pager.pages_in_use
+            rec.t1 = time.perf_counter()
+            m.step_log.append(rec)
 
     def kv_stats(self) -> dict:
         """Static + peak KV-capacity numbers for benchmarks: the decode

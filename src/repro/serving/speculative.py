@@ -249,19 +249,14 @@ class PackedSpeculator:
         """One fused draft+verify round for the whole batch: ONE jitted
         dispatch and NO device->host sync — the returned ``(out_toks,
         n_emit, pre_sampled)`` stay on device for the caller's single
-        ``device_get``.  Returns ``(new_target_cache, that tuple)``."""
+        ``device_get``.  Every array argument is already on the device
+        (the engine uploads them); the ``pre_*`` ones are None on a
+        decode-only step.  Returns ``(new_target_cache, that tuple)``."""
         fn = self._jit_mixed if mixed else self._jit_decode
         cache, self.d_cache, out_toks, n_emit, pre = fn(
-            params, self.d_params, cache, self.d_cache,
-            jnp.asarray(feed), jnp.asarray(d_feed), jnp.asarray(lengths),
-            jnp.asarray(gaps), jnp.asarray(widths), ptab,
-            None if pre_tokens is None else jnp.asarray(pre_tokens),
-            None if pre_positions is None else jnp.asarray(pre_positions),
-            None if pre_q_len is None else jnp.asarray(pre_q_len),
-            None if pre_kv_len is None else jnp.asarray(pre_kv_len),
-            None if pre_ptab is None else jnp.asarray(pre_ptab),
-            step_key, jnp.asarray(temps), jnp.asarray(topks),
-            jnp.asarray(topps))
+            params, self.d_params, cache, self.d_cache, feed, d_feed,
+            lengths, gaps, widths, ptab, pre_tokens, pre_positions,
+            pre_q_len, pre_kv_len, pre_ptab, step_key, temps, topks, topps)
         return cache, (out_toks, n_emit, pre)
 
     def fork_page(self, cache: ModelCache, src, dst) -> ModelCache:
@@ -396,9 +391,10 @@ class PackedSpeculator:
 
         # ---- completing prefills sample their first token as usual --------
         if mixed:
-            pre_keys = jax.random.split(keys[k + 2], rows)
-            pre_sampled = sample_slots(seg_logits[b:], pre_keys, temps[b:],
-                                       topks[b:], topps[b:])
+            with jax.named_scope("sample"):
+                pre_keys = jax.random.split(keys[k + 2], rows)
+                pre_sampled = sample_slots(seg_logits[b:], pre_keys,
+                                           temps[b:], topks[b:], topps[b:])
         else:
             pre_sampled = None
 

@@ -162,7 +162,10 @@ def test_metrics_sanity(served):
     assert m["ttft_s_mean"] > 0 and m["ttft_s_p95"] >= m["ttft_s_p50"]
     assert m["tpot_s_mean"] > 0
     assert m["prefill_calls"] >= 1 and m["prefill_tokens"] == 25
-    assert len(eng.metrics.step_log) == eng.steps
+    log = eng.metrics.step_log  # one StepRecord per step, in order
+    assert [r.step for r in log] == list(range(1, eng.steps + 1))
+    assert sorted(rid for r in log for rid in r.admitted) == \
+        sorted(r.rid for r in reqs)
 
 
 def test_sample_slots_matches_sample_rowwise():

@@ -24,7 +24,9 @@ Checks per captured call:
     ``block_shape`` elements) escapes the operand, at any grid point.
   * **RPL302** — a block shape that does not tile its operand shape.
   * **RPL303** — kernel positional arity != scalar-prefetch count +
-    inputs + outputs + scratch shapes.
+    inputs + outputs + scratch shapes (a kernel that takes ``*refs``
+    may name fewer).  A spec with no block shape (an operand left whole,
+    e.g. in HBM for the kernel's own copies) has no index map to check.
   * **RPL304** — array operands (ndim >= 3; scalar tables ride along as
     2-D int32/float32) disagree on dtype, or the out_shape dtype does.
 
@@ -142,7 +144,8 @@ def _out_list(cap: CapturedCall) -> list[tuple[Any, Any]]:
     return list(zip(specs, shapes))
 
 
-def _kernel_arity(kernel) -> tuple[int, str]:
+def _kernel_arity(kernel) -> tuple[int, bool, str]:
+    """(positional refs, whether it takes ``*refs`` beyond them, name)."""
     f, bound = kernel, set()
     while isinstance(f, functools.partial):
         bound |= set(f.keywords or {})
@@ -151,7 +154,9 @@ def _kernel_arity(kernel) -> tuple[int, str]:
     n = sum(1 for p in sig.parameters.values()
             if p.kind in (p.POSITIONAL_ONLY, p.POSITIONAL_OR_KEYWORD)
             and p.name not in bound)
-    return n, getattr(f, "__name__", str(f))
+    variadic = any(p.kind == p.VAR_POSITIONAL
+                   for p in sig.parameters.values())
+    return n, variadic, getattr(f, "__name__", str(f))
 
 
 def _check_call(cap: CapturedCall, findings: list[Finding]) -> None:
@@ -166,10 +171,10 @@ def _check_call(cap: CapturedCall, findings: list[Finding]) -> None:
     outs = _out_list(cap)
 
     # RPL303: kernel signature arity vs the grid spec
-    n_params, kname = _kernel_arity(cap.kernel)
+    n_params, variadic, kname = _kernel_arity(cap.kernel)
     expected = (cap.num_scalar_prefetch + len(cap.in_specs) + len(outs)
                 + len(cap.scratch_shapes))
-    if n_params != expected:
+    if n_params > expected or (n_params < expected and not variadic):
         flag("RPL303",
              f"kernel '{kname}' takes {n_params} positional refs but the "
              f"grid spec provides {expected} ({cap.num_scalar_prefetch} "
@@ -199,6 +204,8 @@ def _check_call(cap: CapturedCall, findings: list[Finding]) -> None:
               for i, (spec, s) in enumerate(outs)]
     grid_points = list(itertools.product(*(range(g) for g in cap.grid)))
     for label, spec, shape in pairs:
+        if spec.block_shape is None:
+            continue  # the whole operand (e.g. left in HBM for manual DMA)
         bs = tuple(spec.block_shape)
         if len(bs) != len(shape):
             flag("RPL301",
@@ -330,6 +337,26 @@ def default_cases() -> list[KernelCase]:
                 np.asarray(q_start, np.int32), np.asarray(q_len, np.int32),
                 np.asarray(kv_len, np.int32), max_q=max_q)
         cases.append(KernelCase(f"ragged_paged[segs={segs}]", ragged))
+
+    # the multi-page block walk — ::test_ragged_paged_kernel_vs_gather_oracle
+    # block cases: 32-token pages, 40 a segment, three blocks of up to 16
+    # pages, the last partial; segments end on, past and inside a block
+    def blocks():
+        segs = [(1, 512), (1, 1025), (0, 0), (8, 520), (1, 100)]
+        S, ps_b, mp_b = len(segs), 32, 40
+        P = 1 + sum(-(-kv // ps_b) for _, kv in segs)
+        pt = np.zeros((S, mp_b), np.int32)
+        free = iter(range(1, P))
+        for s, (_, kl) in enumerate(segs):
+            for i in range(-(-kl // ps_b)):
+                pt[s, i] = next(free)
+        ql = np.asarray([q for q, _ in segs], np.int32)
+        return pallas_ragged_paged_attention(
+            z((int(ql.sum()), Hq, D)), z((P, Hkv, ps_b, D)),
+            z((P, Hkv, ps_b, D)), pt,
+            np.concatenate([[0], np.cumsum(ql)[:-1]]).astype(np.int32), ql,
+            np.asarray([kv for _, kv in segs], np.int32), max_q=max_q)
+    cases.append(KernelCase("ragged_paged[blocks,ps32,mp40]", blocks))
 
     # speculative verify windows — the PackedSpeculator's decode-segment
     # geometries: K+1-wide verify segments (max_q = 5 at K = 4, one token

@@ -85,8 +85,8 @@ def ragged_paged_attention(q, k_pool, v_pool, seg_page_table, q_start,
     engine's chunk size).  Returns (T, Hq, D).
 
       gather : per-segment page gather + masked softmax (the jnp oracle)
-      pallas : one kernel, grid (segment x kv-head, page), scalar-prefetch
-               segment + page tables steering the DMA
+      pallas : one kernel, grid (kv-head x segment, block of pages),
+               scalar-prefetch segment + page tables steering the copies
     """
     if impl == "gather":
         return ref.ragged_paged_reference(q, k_pool, v_pool, seg_page_table,

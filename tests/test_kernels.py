@@ -225,23 +225,59 @@ def _ragged_valid_rows(q_start, q_len, T):
     return valid
 
 
-@pytest.mark.parametrize("segs", [
+# (Hq, Hkv, D, page_size, max_pages, max_q)
+RAGGED_GEOM = (4, 2, 16, 4, 6, 8)
+# 32-token pages, 40 a segment: pages_per_block gives 16-page blocks of 512
+# keys, so three blocks, the last one 8 pages (40 is no multiple of 16)
+BLOCK_GEOM = (4, 2, 16, 32, 40, 8)
+BLOCK_CASES = {
+    # kv_len exactly on a block boundary, one token past it, below a block
+    "on-boundary": [(1, 512), (1, 1024)],
+    "one-past": [(1, 513), (1, 1025)],
+    "below-one-block": [(1, 100), (1, 511)],
+    # the last, partial block of a full segment
+    "ragged-tail": [(1, 1280), (1, 1100)],
+    "inactive-between": [(1, 700), (0, 0), (1, 900)],
+    # a K+1 = 5 token speculative verify segment straddling a boundary
+    "verify": [(5, 514), (1, 30)],
+    # prefill chunks whose causal edge falls inside a block, and one that
+    # straddles a block boundary
+    "prefill-causal-edge": [(8, 520), (8, 516), (3, 1030)],
+}
+
+
+@pytest.mark.parametrize("segs,geom,copies", [
     # mixed: two decode slots, an inactive segment, two prefill chunks
-    [(1, 7), (1, 13), (0, 0), (8, 8), (5, 11)],
+    pytest.param([(1, 7), (1, 13), (0, 0), (8, 8), (5, 11)], RAGGED_GEOM,
+                 None, id="segs0"),
     # decode-only packing (every segment one token)
-    [(1, 5), (1, 9), (1, 16), (1, 1)],
+    pytest.param([(1, 5), (1, 9), (1, 16), (1, 1)], RAGGED_GEOM, None,
+                 id="segs1"),
     # empty-prefill: idle rows ride along as q_len == 0 segments
-    [(1, 6), (0, 0), (0, 0)],
+    pytest.param([(1, 6), (0, 0), (0, 0)], RAGGED_GEOM, None, id="segs2"),
     # prefill-only, partial last pages
-    [(7, 7), (3, 15)],
+    pytest.param([(7, 7), (3, 15)], RAGGED_GEOM, None, id="segs3"),
+    # the multi-page block walk, both ways pages reach the kernel: its own
+    # double-buffered copies, and the grid pipeline's page inputs
+    *[pytest.param(segs, BLOCK_GEOM, copies, id=f"{copies}-{name}")
+      for copies in ("manual", "pipelined")
+      for name, segs in BLOCK_CASES.items()],
 ])
-def test_ragged_paged_kernel_vs_gather_oracle(segs):
+def test_ragged_paged_kernel_vs_gather_oracle(monkeypatch, segs, geom,
+                                              copies):
     """One ragged dispatch over mixed decode + prefill segments must equal
     the per-segment gather + masked softmax oracle, including causal
     masking within prefill chunks and inactive segments."""
+    from repro.kernels import ragged_attention
+    if copies is not None:
+        monkeypatch.setattr(ragged_attention, "_manual_copies",
+                            lambda d: copies == "manual")
     rng = np.random.default_rng(0)
-    Hq, Hkv, D, ps, mp, max_q = 4, 2, 16, 4, 6, 8
+    Hq, Hkv, D, ps, mp, max_q = geom
     args = _ragged_case(rng, segs, Hq, Hkv, D, ps, mp, max_q)
+    if geom == BLOCK_GEOM:
+        ppb = ragged_attention.pages_per_block(ps, mp, max_q * Hq // Hkv)
+        assert -(-mp // ppb) >= 3 and mp % ppb
     want = kops.ragged_paged_attention(*args, max_q=max_q, impl="gather")
     got = kops.ragged_paged_attention(*args, max_q=max_q, impl="pallas",
                                       interpret=True)
@@ -249,6 +285,35 @@ def test_ragged_paged_kernel_vs_gather_oracle(segs):
     np.testing.assert_allclose(np.asarray(got, np.float32)[valid],
                                np.asarray(want, np.float32)[valid],
                                atol=2e-6, rtol=2e-6)
+
+
+def test_ragged_pages_per_block_from_shapes():
+    """The block size is a function of the shapes alone, within
+    [1, max_pages]; the decode sub-call gets 512 keys at page size 16, and
+    the float32 score tile of the prefill (max_q 256) and decode (max_q 1)
+    sub-calls at G = 6, D = 128 stays within the VMEM the kernel asks for."""
+    import inspect
+    from repro.kernels import ragged_attention as ra
+    assert list(inspect.signature(ra.pages_per_block).parameters) == [
+        "page_size", "max_pages", "rows"]
+    for ps in (1, 4, 16, 32, 128):
+        for mp in (1, 3, 40, 256, 1000):
+            for rows in (1, 6, 30, 1536, 4096, 1 << 20):
+                got = ra.pages_per_block(ps, mp, rows)
+                assert 1 <= got <= mp
+                assert got == ra.pages_per_block(ps, mp, rows)
+    # the decode sub-call (max_q 1, G 6): 512 keys; the longctx decode
+    # grid then walks 256 / 32 = 8 blocks, not 256 pages
+    assert ra.pages_per_block(16, 256, 1 * 6) * 16 == 512
+    t, g, d = 1024, 6, 128
+    for max_q in (256, 1):
+        rows = max_q * g
+        keys = ra.pages_per_block(16, 256, rows) * 16
+        tile = rows * keys * 4
+        assert tile <= ra.SCORE_TILE_BYTES
+        assert tile < ra.vmem_limit_bytes(t, max_q, g, d, 2, keys)
+    # the prefill sub-call (max_q 256): 256 keys
+    assert ra.pages_per_block(16, 256, 256 * g) * 16 == 256
 
 
 def test_ragged_decode_only_matches_paged_decode_oracle():

@@ -10,6 +10,9 @@ Geometries: ``qwen1.5-0.5b`` (Hq = Hkv = 16, D = 64) and a GQA stack
 (Hq = 32, Hkv = 8, D = 128), with the unified step's two ragged sub-batches
 (8 decode slots at ``max_q = 1``; 2 prefill rows of 128-token chunks) and
 paged decode, over 128 pages of 16 tokens per request (``max_seq`` 2048).
+The benchmark's own geometry, minitron-8b (Hq = 48, Hkv = 8, D = 128, so
+G = 6), compiles at its cells' shapes: 24 or 32 decode slots at
+``max_q = 1`` and 4 prefill rows of 256-token chunks, over 256 pages of 16.
 
 The topology is described only inside the module fixture (one process may
 load the TPU library at a time), and the persistent compile cache is off
@@ -27,6 +30,9 @@ from repro.kernels.ragged_attention import pallas_ragged_paged_attention
 PAGE, MAX_PAGES, POOL = 16, 128, 8 * 128 + 1
 SLOTS, ROWS, CHUNK = 8, 2, 128
 GEOMETRIES = {"qwen1.5-0.5b": (16, 16, 64), "gqa": (32, 8, 128)}
+# (Hq, Hkv, D), max_pages, pool pages: bench/configs/minitron-8b-l8.json
+# and the engine of bench/traffic/*.json
+BENCH_GEOMETRY, BENCH_MAX_PAGES, BENCH_POOL = (48, 8, 128), 256, 6145
 
 
 @pytest.fixture(scope="module")
@@ -69,6 +75,21 @@ def test_ragged_kernel_compiles(one_chip, geometry, dtype, sub_batch):
     args = _shapes(one_chip, ((t, hq, d), dtype), pool, pool,
                    ((segs, MAX_PAGES), jnp.int32), ((segs,), jnp.int32),
                    ((segs,), jnp.int32), ((segs,), jnp.int32))
+    _assert_kernel(lambda q, k, v, pt, qs, ql, kl:
+                   pallas_ragged_paged_attention(q, k, v, pt, qs, ql, kl,
+                                                 max_q=max_q), args)
+
+
+@pytest.mark.parametrize("segs,max_q", [(24, 1), (32, 1), (4, 256)],
+                         ids=["decode24", "decode32", "prefill4x256"])
+def test_ragged_kernel_compiles_at_bench_geometry(one_chip, segs, max_q):
+    hq, hkv, d = BENCH_GEOMETRY
+    dt = jnp.bfloat16
+    pool = ((BENCH_POOL, hkv, PAGE, d), dt)
+    args = _shapes(one_chip, ((segs * max_q, hq, d), dt), pool, pool,
+                   ((segs, BENCH_MAX_PAGES), jnp.int32),
+                   ((segs,), jnp.int32), ((segs,), jnp.int32),
+                   ((segs,), jnp.int32))
     _assert_kernel(lambda q, k, v, pt, qs, ql, kl:
                    pallas_ragged_paged_attention(q, k, v, pt, qs, ql, kl,
                                                  max_q=max_q), args)

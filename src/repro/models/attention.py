@@ -510,6 +510,7 @@ def attention_block(spec: ModelSpec, ctx: ModelContext, params: dict,
         # column-sharded wq/wk/wv gave this rank n_heads/tp heads; the
         # row-sharded wo leaves a partial sum — the layer's first of two
         # all-reduces restores the replicated residual stream
-        y = jax.lax.psum(y, ctx.tp_axis)
+        with jax.named_scope("tp_psum"):
+            y = jax.lax.psum(y, ctx.tp_axis)
     y = ctx.shard(y, "batch", "seq_res", "act_embed")
     return y, new_cache

@@ -44,5 +44,6 @@ def mlp_block(spec: ModelSpec, ctx: ModelContext, params: dict,
     if ctx.tp_axis is not None:
         # column-sharded w_up/w_gate, row-sharded w_down: the partial
         # products all-reduce here — the layer pair's second collective
-        y = jax.lax.psum(y, ctx.tp_axis)
+        with jax.named_scope("tp_psum"):
+            y = jax.lax.psum(y, ctx.tp_axis)
     return ctx.shard(y, "batch", "seq_res", "act_embed")

@@ -122,8 +122,10 @@ class Model:
                 # the step's single logits gather (tied heads stay
                 # replicated because the embedding table must serve
                 # full-vocab lookups)
-                logits = jax.lax.all_gather(logits, self.ctx.tp_axis,
-                                            axis=logits.ndim - 1, tiled=True)
+                with jax.named_scope("tp_gather"):
+                    logits = jax.lax.all_gather(
+                        logits, self.ctx.tp_axis, axis=logits.ndim - 1,
+                        tiled=True)
             return self.ctx.shard(logits, "batch", "seq", "act_vocab")
 
     # -- training / encoder forward ---------------------------------------------

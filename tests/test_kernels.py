@@ -230,6 +230,9 @@ RAGGED_GEOM = (4, 2, 16, 4, 6, 8)
 # 32-token pages, 40 a segment: pages_per_block gives 16-page blocks of 512
 # keys, so three blocks, the last one 8 pages (40 is no multiple of 16)
 BLOCK_GEOM = (4, 2, 16, 32, 40, 8)
+# the same walk at G = 1 (as many query as kv heads: one shard of a
+# multi-head model at tp > 1), one query row per kv head and decode segment
+MHA_BLOCK_GEOM = (2, 2, 16, 32, 40, 8)
 BLOCK_CASES = {
     # kv_len exactly on a block boundary, one token past it, below a block
     "on-boundary": [(1, 512), (1, 1024)],
@@ -262,6 +265,8 @@ BLOCK_CASES = {
     *[pytest.param(segs, BLOCK_GEOM, copies, id=f"{copies}-{name}")
       for copies in ("manual", "pipelined")
       for name, segs in BLOCK_CASES.items()],
+    *[pytest.param(segs, MHA_BLOCK_GEOM, "manual", id=f"manual-mha-{name}")
+      for name, segs in BLOCK_CASES.items()],
 ])
 def test_ragged_paged_kernel_vs_gather_oracle(monkeypatch, segs, geom,
                                               copies):
@@ -275,7 +280,7 @@ def test_ragged_paged_kernel_vs_gather_oracle(monkeypatch, segs, geom,
     rng = np.random.default_rng(0)
     Hq, Hkv, D, ps, mp, max_q = geom
     args = _ragged_case(rng, segs, Hq, Hkv, D, ps, mp, max_q)
-    if geom == BLOCK_GEOM:
+    if geom in (BLOCK_GEOM, MHA_BLOCK_GEOM):
         ppb = ragged_attention.pages_per_block(ps, mp, max_q * Hq // Hkv)
         assert -(-mp // ppb) >= 3 and mp % ppb
     want = kops.ragged_paged_attention(*args, max_q=max_q, impl="gather")

@@ -323,3 +323,72 @@ def test_sharded_forward_logits_match_one_device():
                                        atol=1e-5, rtol=1e-5)
         print("OK")
     """)
+
+
+def test_compiled_step_holds_the_counted_collectives():
+    """The compiled tp=4 step (both packed profiles) executes exactly
+    ``collective_stats(...)[0]`` all-reduces and all-gathers: 2 per layer
+    inside the layer scan (counted once per trip) and the logits
+    gather."""
+    _mesh_run(4, """
+        import functools, re
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.serving import sharded as shard
+        from repro.serving.sharded import collective_stats
+
+        def executed(text):
+            comps, cur, entry = {}, None, None
+            for line in text.splitlines():
+                head = re.match(r"^(ENTRY )?%([\\w.\\-]+) .*\\{$", line)
+                if head:
+                    cur = head.group(2)
+                    comps[cur] = [0, []]
+                    entry = cur if head.group(1) else entry
+                    continue
+                if cur is None:
+                    continue
+                if re.search(r" (all-reduce|all-gather)(-start)?\\(", line):
+                    comps[cur][0] += 1
+                for kind, callee in re.findall(
+                        r"(body|calls|to_apply)=%([\\w.\\-]+)", line):
+                    trips = re.search(r'"known_trip_count":\\{"n":"(\\d+)"',
+                                      line) if kind == "body" else None
+                    comps[cur][1].append(
+                        (callee, int(trips.group(1)) if trips else 1))
+
+            def count(name):
+                own, calls = comps[name]
+                return own + sum(n * count(c) for c, n in calls)
+            return count(entry)
+
+        tspec = ModelSpec(name="mha", d_model=128, n_layers=3, n_heads=4,
+                          n_kv_heads=4, d_head=32, d_ff=344, vocab=512,
+                          attn=AttnSpec(kind="full", causal=True),
+                          act="swiglu")
+        m = build_model(tspec, param_dtype=jnp.float32,
+                        compute_dtype=jnp.float32, cache_layout="paged",
+                        kv_page_size=8)
+        mesh = shard.make_engine_mesh(4, 1)
+        sds = lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, s))
+        p = jax.tree.map(sds, jax.eval_shape(m.init, jax.random.key(0)),
+                         shard.param_pspecs(m, 4, 1))
+        c = jax.tree.map(sds, jax.eval_shape(functools.partial(
+            m.init_cache, 2, 32, layout="paged", n_pages=9)),
+            shard.cache_pspecs(m, 4, 1))
+        rep = NamedSharding(mesh, P())
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=rep)
+        f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
+        key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                   sharding=rep)
+        for max_q, n_decode, t, n in ((4, 2, 2 + 2 * 4, 4), (1, 0, 2, 2)):
+            fn = shard.build_sharded_step(m, mesh, 4, 1, max_slots=2,
+                                          max_q=max_q, n_decode=n_decode)
+            text = fn.lower(p, c, i32(t), i32(t), i32(n), i32(n), i32(n),
+                            i32(n, 4), key, f32(n), i32(n),
+                            f32(n)).compile().as_text()
+            want = collective_stats(tspec, 4, 1, t, n)[0]
+            assert want == 2 * tspec.n_layers + 1
+            assert executed(text) == want, (max_q, executed(text), want)
+        print("OK")
+    """)

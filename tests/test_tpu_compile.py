@@ -13,6 +13,9 @@ paged decode, over 128 pages of 16 tokens per request (``max_seq`` 2048).
 The benchmark's own geometry, minitron-8b (Hq = 48, Hkv = 8, D = 128, so
 G = 6), compiles at its cells' shapes: 24 or 32 decode slots at
 ``max_q = 1`` and 4 prefill rows of 256-token chunks, over 256 pages of 16.
+So does one shard of deepseek-7b at tp=4 (Hq = Hkv = 8, D = 128, so G = 1:
+one query row per kv head and decode segment), at its cell's 16 decode
+slots and 2 prefill rows of 256, over its per-chip pool.
 
 The topology is described only inside the module fixture (one process may
 load the TPU library at a time), and the persistent compile cache is off
@@ -33,6 +36,9 @@ GEOMETRIES = {"qwen1.5-0.5b": (16, 16, 64), "gqa": (32, 8, 128)}
 # (Hq, Hkv, D), max_pages, pool pages: bench/configs/minitron-8b-l8.json
 # and the engine of bench/traffic/*.json
 BENCH_GEOMETRY, BENCH_MAX_PAGES, BENCH_POOL = (48, 8, 128), 256, 6145
+# one tp=4 shard of bench/configs/deepseek-7b-tp4.json; the pool of
+# bench/traffic/chat.json
+SHARD_GEOMETRY, SHARD_POOL = (8, 8, 128), 3176
 
 
 @pytest.fixture(scope="module")
@@ -80,12 +86,17 @@ def test_ragged_kernel_compiles(one_chip, geometry, dtype, sub_batch):
                                                  max_q=max_q), args)
 
 
-@pytest.mark.parametrize("segs,max_q", [(24, 1), (32, 1), (4, 256)],
-                         ids=["decode24", "decode32", "prefill4x256"])
-def test_ragged_kernel_compiles_at_bench_geometry(one_chip, segs, max_q):
-    hq, hkv, d = BENCH_GEOMETRY
+@pytest.mark.parametrize("geometry,n_pages,segs,max_q", [
+    (BENCH_GEOMETRY, BENCH_POOL, 24, 1), (BENCH_GEOMETRY, BENCH_POOL, 32, 1),
+    (BENCH_GEOMETRY, BENCH_POOL, 4, 256), (SHARD_GEOMETRY, SHARD_POOL, 16, 1),
+    (SHARD_GEOMETRY, SHARD_POOL, 2, 256)],
+    ids=["decode24", "decode32", "prefill4x256", "tp4-decode16",
+         "tp4-prefill2x256"])
+def test_ragged_kernel_compiles_at_bench_geometry(one_chip, geometry, n_pages,
+                                                  segs, max_q):
+    hq, hkv, d = geometry
     dt = jnp.bfloat16
-    pool = ((BENCH_POOL, hkv, PAGE, d), dt)
+    pool = ((n_pages, hkv, PAGE, d), dt)
     args = _shapes(one_chip, ((segs * max_q, hq, d), dt), pool, pool,
                    ((segs, BENCH_MAX_PAGES), jnp.int32),
                    ((segs,), jnp.int32), ((segs,), jnp.int32),

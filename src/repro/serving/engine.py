@@ -209,6 +209,9 @@ class StepRecord:
     decode: list = field(default_factory=list)  # live decode kv_lens
     prefill: list = field(default_factory=list)  # [(q_len, kv_len)]
     sampled: int = 0  # segments whose sampled token is used
+    #: of those, segments whose temperature is > 0; a unified step ran
+    #: ``sample_slots``' filter branch exactly when this is > 0
+    stochastic: int = 0
     pages_in_use: int = 0  # after the step (paged layout)
     preempted: int = 0  # preemptions during the step
     admitted: list = field(default_factory=list)  # rids admitted
@@ -1098,6 +1101,11 @@ class ServeEngine:
         the request holds; its page-table row falls back to the null page
         so the now-garbage decode row writes somewhere harmless."""
         self.free_slots.append(slot)
+        if self._temps[slot] > 0.0:
+            # an idle slot samples greedy, so a batch whose live rows are
+            # all greedy skips the vocabulary filters (``sample_slots``)
+            self._temps[slot] = 0.0
+            self._dev_sampling = None
         if self.paged:
             if self.prefix is not None:
                 # full pages of what this request actually processed stay
@@ -1377,6 +1385,8 @@ class ServeEngine:
                 rec.prefill = [(w, int(kv_len[nslots + r]))
                                for r, w in widths.items()]
                 rec.sampled = len(self.active) + completing
+                rec.stochastic = int(
+                    ((temps if mixed else self._temps) > 0.0).sum())
         with span("engine.upload"):
             if mixed:
                 fn, seg_start = self._jit_unified, self._seg_start_dev
@@ -1540,6 +1550,7 @@ class ServeEngine:
                 rec.prefill = [(w, int(pre[3][r]))
                                for r, w in widths.items()]
                 rec.sampled = len(self.active) + completing
+                rec.stochastic = int((temps > 0.0).sum())
         with span("engine.upload"):
             if self._dev_ptab is None:
                 self._dev_ptab = self._up(self._ptab)

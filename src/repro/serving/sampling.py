@@ -5,11 +5,12 @@ Two entry points:
   ``sample``       — one SamplingConfig for the whole batch (Python-level
                      branching on the config; used by the speculative and
                      beam decoders and as the semantics oracle).
-  ``sample_slots`` — per-row sampling parameters as device arrays, fully
-                     branch-free, so a single jitted call can sample every
-                     engine slot in one shot even when requests mix greedy
-                     and stochastic configs.  Row semantics match
-                     ``sample`` exactly (temperature <= 0 means greedy).
+  ``sample_slots`` — per-row sampling parameters as device arrays, with
+                     no Python-level branching, so a single jitted call can
+                     sample every engine slot in one shot even when
+                     requests mix greedy and stochastic configs.  Row
+                     semantics match ``sample`` exactly (temperature <= 0
+                     means greedy).
 """
 
 from __future__ import annotations
@@ -54,10 +55,23 @@ def sample_slots(logits: jax.Array, keys: jax.Array, temperature: jax.Array,
     temperature/top_p: (B,) f32; top_k: (B,) i32 (0 disables).
     Returns (B,) int32.  Rows with temperature <= 0 are greedy argmax —
     identical to ``sample`` with the same per-row config.
-    """
-    v = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+    The temperature divide, both vocabulary sorts and the draw run inside
+    one ``lax.cond`` taken on the device only when some row samples, so an
+    all-greedy batch costs one argmax over (B, V).
+    """
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jax.lax.cond(
+        jnp.any(temperature > 0.0),
+        lambda: _filtered_draw(logits, keys, temperature, top_k, top_p,
+                               greedy),
+        lambda: greedy)
+
+
+def _filtered_draw(logits, keys, temperature, top_k, top_p, greedy):
+    """Temperature, top-k, top-p and the categorical draw for every row;
+    greedy rows keep ``greedy``."""
+    v = logits.shape[-1]
     lf = logits.astype(jnp.float32) / jnp.maximum(temperature, 1e-8)[:, None]
     # top-k: kth-largest threshold per row (k clipped into range; rows with
     # top_k <= 0 keep everything)

@@ -74,10 +74,12 @@ _COMP = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s.*\{\s*$")
 
 def instructions(text: str) -> list:
     """The HLO's computations and instructions without their metadata,
-    names replaced by their order of definition (a scope may rename an
-    instruction; it must not change one)."""
+    names replaced by their order of definition and the parameter names
+    of computation signatures dropped (a scope may rename an instruction
+    or a ``cond`` branch's parameter; it must not change one)."""
     text = re.sub(r",?\s*metadata=\{[^}]*\}", "", text)
-    lines = [ln for ln in text.splitlines()
+    lines = [re.sub(r"([(,]\s*)[\w.\-]+: ", r"\1", ln) if _COMP.match(ln)
+             else ln for ln in text.splitlines()
              if _DEF.match(ln) or _COMP.match(ln)]
     names: dict = {}
     for ln in lines:

@@ -186,6 +186,150 @@ def test_sample_slots_matches_sample_rowwise():
         assert int(got[i]) == int(want[0]), (i, c)
 
 
+def _sample_slots_oracle(logits, keys, temperature, top_k, top_p):
+    """``sample_slots`` as it was before its filters moved behind a
+    ``lax.cond``: every row divided, sorted twice and drawn, every step."""
+    v = logits.shape[-1]
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    lf = logits.astype(jnp.float32) / jnp.maximum(temperature, 1e-8)[:, None]
+    desc = jnp.sort(lf, axis=-1)[:, ::-1]
+    k_idx = jnp.clip(top_k - 1, 0, v - 1)
+    kth = jnp.take_along_axis(desc, k_idx[:, None], axis=-1)
+    lf = jnp.where((top_k[:, None] > 0) & (lf < kth), -jnp.inf, lf)
+    desc = jnp.sort(lf, axis=-1)[:, ::-1]
+    probs = jax.nn.softmax(desc, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    cutoff_idx = jnp.sum(cum < top_p[:, None], axis=-1)
+    cutoff = jnp.take_along_axis(desc, cutoff_idx[:, None], axis=-1)
+    lf = jnp.where((top_p[:, None] < 1.0) & (lf < cutoff), -jnp.inf, lf)
+
+    stochastic = jax.vmap(jax.random.categorical)(keys, lf).astype(jnp.int32)
+    return jnp.where(temperature <= 0.0, greedy, stochastic)
+
+
+# (temperature, top_k, top_p) per row
+_SAMPLING_CASES = {
+    "all_greedy": [(0.0, 0, 1.0), (0.0, 5, 1.0), (0.0, 0, 0.5),
+                   (0.0, 3, 0.9), (-1.0, 0, 1.0), (0.0, 40, 0.2)],
+    "one_stochastic": [(0.0, 0, 1.0), (0.0, 5, 0.9), (0.9, 0, 1.0),
+                       (0.0, 0, 1.0), (0.0, 2, 1.0), (0.0, 0, 0.7)],
+    "all_stochastic": [(0.7, 0, 1.0), (1.0, 5, 1.0), (0.5, 0, 0.8),
+                       (1.3, 20, 0.9), (0.2, 1, 1.0), (2.0, 0, 0.99)],
+    "top_k_only": [(1.0, 1, 1.0), (0.8, 5, 1.0), (1.0, 50, 1.0),
+                   (1.5, 3, 1.0), (0.0, 7, 1.0), (1.0, 100000, 1.0)],
+    "top_p_only": [(1.0, 0, 0.5), (0.8, 0, 0.9), (1.2, 0, 0.99),
+                   (1.0, 0, 0.05), (0.0, 0, 0.7), (2.0, 0, 0.3)],
+    "top_k_top_p": [(1.0, 5, 0.5), (0.8, 50, 0.9), (1.2, 2, 0.99),
+                    (1.0, 20, 0.05), (0.0, 10, 0.7), (2.0, 200, 0.3)],
+}
+
+
+@pytest.mark.parametrize("vocab", [1000, 257])
+@pytest.mark.parametrize("case", sorted(_SAMPLING_CASES))
+def test_sample_slots_matches_unconditional_filters(case, vocab):
+    """Skipping the filters for an all-greedy batch changes no token: the
+    same keys give the same tokens as the unconditional body, for greedy,
+    mixed and stochastic batches and each filter alone or together."""
+    rows = _SAMPLING_CASES[case]
+    rng = np.random.default_rng(vocab)
+    logits = jnp.asarray(3.0 * rng.normal(size=(len(rows), vocab)),
+                         jnp.float32)
+    keys = jax.random.split(jax.random.key(17), len(rows))
+    temps = jnp.asarray([r[0] for r in rows], jnp.float32)
+    topks = jnp.asarray([r[1] for r in rows], jnp.int32)
+    topps = jnp.asarray([r[2] for r in rows], jnp.float32)
+    args = (logits, keys, temps, topks, topps)
+    got = np.asarray(jax.jit(sample_slots)(*args))
+    want = np.asarray(jax.jit(_sample_slots_oracle)(*args))
+    np.testing.assert_array_equal(got, want)
+    greedy = np.asarray(jnp.argmax(logits, axis=-1))
+    is_greedy = np.asarray(temps) <= 0
+    np.testing.assert_array_equal(got[is_greedy], greedy[is_greedy])
+
+
+def _primitives(jaxpr, into_cond: bool) -> list[str]:
+    """Primitive names of ``jaxpr`` and of the jaxprs nested in it;
+    ``cond`` branches only when ``into_cond``."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name == "cond" and not into_cond:
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    names += _primitives(inner, into_cond)
+    return names
+
+
+def test_sample_slots_sorts_only_inside_cond():
+    """The vocabulary sorts and the temperature divide live in the
+    ``cond`` branch alone: outside it only the argmax and the any-row
+    test run, so a greedy batch pays for neither."""
+    b, v = 3, 64
+    jaxpr = jax.make_jaxpr(sample_slots)(
+        jnp.zeros((b, v), jnp.bfloat16),
+        jax.random.split(jax.random.key(0), b),
+        jnp.zeros((b,), jnp.float32), jnp.zeros((b,), jnp.int32),
+        jnp.ones((b,), jnp.float32)).jaxpr
+    outside = _primitives(jaxpr, into_cond=False)
+    assert outside.count("cond") == 1, outside
+    assert "sort" not in outside and "div" not in outside, outside
+    [cond] = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    branches = [_primitives(br.jaxpr, into_cond=True)
+                for br in cond.params["branches"]]
+    assert sorted(br.count("sort") for br in branches) == [0, 2], branches
+    assert any("div" in br for br in branches), branches
+
+
+@pytest.mark.parametrize("mode", ["unified", "spec"])
+def test_step_record_counts_stochastic_segments(served, mode):
+    """``StepRecord.stochastic`` reads 0 on every step of greedy traffic,
+    and otherwise the number of temperature > 0 requests the step
+    sampled a token for; a finished stochastic request leaves its idle
+    slot greedy."""
+    spec, model, params = served
+    kw = dict(record_step_log=True)
+    draft = {}
+    if mode == "spec":
+        kw["n_spec"] = 2
+        draft = dict(draft_model=model, draft_params=params)
+    eng = ServeEngine(model, params, _unified_cfg(True, **kw),
+                      rng=jax.random.key(3), **draft)
+
+    def drive(reqs):
+        for r in reqs:
+            eng.submit(r)
+        want = []
+        while eng.busy:
+            before = [len(r.output) for r in reqs]
+            eng.step()
+            grew = [len(r.output) > n for r, n in zip(reqs, before)]
+            want.append(sum(g for g, r in zip(grew, reqs)
+                            if r.sampling.temperature > 0))
+        return want
+
+    greedy = [Request(prompt=[3 + i] * (2 + 3 * i), max_new_tokens=5)
+              for i in range(3)]
+    n0 = len(eng.metrics.step_log)
+    assert drive(greedy) == [0] * (len(eng.metrics.step_log) - n0)
+    assert all(r.stochastic == 0 for r in eng.metrics.step_log)
+
+    mixed = [Request(prompt=[9, 8, 7, 6, 5], max_new_tokens=8,
+                     sampling=SamplingConfig(temperature=0.8, top_k=20)),
+             Request(prompt=[1, 2, 3], max_new_tokens=20),
+             Request(prompt=[4] * 9, max_new_tokens=4,
+                     sampling=SamplingConfig(temperature=1.0, top_p=0.9)),
+             Request(prompt=[6, 6], max_new_tokens=20)]
+    n0 = len(eng.metrics.step_log)
+    want = drive(mixed)
+    got = [r.stochastic for r in eng.metrics.step_log[n0:]]
+    assert got == want
+    assert max(got) == 2 and got[-1] == 0, got
+
+
 def test_decode_feed_stays_on_device(served):
     """Steady-state decode never re-uploads the host token mirror: the
     sampled tokens feed the next step from the donated device buffer.
